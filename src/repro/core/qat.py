@@ -24,6 +24,7 @@ Modes
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, replace
 from typing import Any, Dict, Optional
 
@@ -61,6 +62,9 @@ class QuantCtx:
     # (requires attach_w4a8_exports on the served tree — strict, no fallback).
     weights_layout: str = "bf16"
     w4a8_backend: str = "auto"           # auto | pallas | ref
+    # Serving mesh (None off a mesh): GSPMD cannot partition a Pallas
+    # call, so the kernels run per device of it under a shard_map
+    mesh: Any = None
 
     @property
     def off(self) -> bool:
@@ -80,13 +84,14 @@ def make_ctx(policy: str | PrecisionPolicy, mode: str = "train",
              act_calib_method: str = "quantile",
              attn_shard_mode: str = "", batch_axes: tuple = (),
              weights_layout: str = "bf16",
-             w4a8_backend: str = "auto") -> QuantCtx:
+             w4a8_backend: str = "auto", mesh=None) -> QuantCtx:
     if isinstance(policy, str):
         policy = parse_policy(policy)
     return QuantCtx(policy=policy, mode=mode,
                     act_calib_method=act_calib_method,
                     attn_shard_mode=attn_shard_mode, batch_axes=batch_axes,
-                    weights_layout=weights_layout, w4a8_backend=w4a8_backend)
+                    weights_layout=weights_layout, w4a8_backend=w4a8_backend,
+                    mesh=mesh)
 
 
 # --------------------------------------------------------------------------
@@ -183,7 +188,7 @@ def w4a8_qlinear(ctx: QuantCtx, x: jnp.ndarray, exp: Dict[str, Any]) -> jnp.ndar
     """Packed-int4-weight x dynamic-int8-activation linear (serve hot path)."""
     from repro.kernels.w4a8.ops import w4a8_linear
     return w4a8_linear(x, exp, out_dtype=x.dtype,
-                       use_pallas=w4a8_use_pallas(ctx))
+                       use_pallas=w4a8_use_pallas(ctx), mesh=ctx.mesh)
 
 
 def cache_dtype(ctx: QuantCtx):
@@ -355,6 +360,7 @@ def export_linear_int(p: Dict[str, Any], weight_bits: int) -> Dict[str, Any]:
     return out
 
 
+@functools.partial(jax.jit, static_argnames="trained_bits")
 def export_linear_w4(p: Dict[str, Any], trained_bits: int = 4) -> Dict[str, Any]:
     """Pack one linear into the serve-path int4 layout.
 
@@ -371,6 +377,10 @@ def export_linear_w4(p: Dict[str, Any], trained_bits: int = 4) -> Dict[str, Any]
     No Python-bool leaves (``export_linear_int``'s ``"packed"``): the export
     rides the param pytree through ``jax.jit`` / ``lax.scan``, where a bool
     leaf would become a tracer.
+
+    Jitted so the f32 quantize chain fuses: run op by op, a layer-stacked
+    MLP weight of qwen2.5-3b holds three 3.2 GB f32 temporaries at once,
+    which with the bf16 tree filled a 16 GB chip.
     """
     from repro.core.quantizer import qbounds
     w = p["w"]

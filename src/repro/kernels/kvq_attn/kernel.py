@@ -1,18 +1,27 @@
-"""Pallas TPU kernel: flash-decode attention over an int8/int4 KV cache.
+"""Pallas TPU kernels: flash-decode attention over an int8/int4 KV cache,
+plus the paged pool's gather-dequant and copy-on-write block copy.
 
 TPU adaptation of the paper's "quantization fused into the attention kernel"
 policy (the CUDA flash kernel encapsulates the softmax; our Pallas kernel
-encapsulates cache *dequantization*): K/V tiles are dequantized VMEM-locally
-(int8 load -> VREG multiply by per-token scale), so HBM traffic is 2-4x lower
-than a bf16 cache and no dequantized copy ever exists in HBM.
+encapsulates cache *dequantization*): integer K/V tiles are read straight
+into VMEM and their per-token scales applied there, so HBM traffic is 2-4x
+lower than a bf16 cache and no dequantized copy ever exists in HBM.
 
-Grid (B, H, S/BS) with online-softmax state (m, l, acc) in VMEM scratch,
-carried across the S tiles (innermost grid dim). GQA maps query head h to
-cache head h // (H // Hkv) in the BlockSpec index maps.
+Both attention kernels run the same online-softmax walk (``_kernel``): grid
+(B, Hkv, tiles), one step per *KV* head, state (m, l, acc) in VMEM scratch
+carried across the cache tiles (innermost grid dim). The q operand arrives
+pre-grouped as (B, Hkv, R, D): all R query rows that read one cache head (the
+GQA group, times the verify window for speculative decoding) stacked on the
+sublane axis, R a multiple of 8 (ops.py pads). Each int8 (bs, D) K/V tile is
+therefore fetched once per KV head, and the score / accumulator tiles are
+full-sublane (R, bs) / (R, D) VREGs. Per-row valid extents ride along as an
+(R, 1) int32 column.
 
-BS = 512 cache tokens per tile: k/v tiles are (512, D) int8 = 64 KiB each at
-D=128, scales 2 KiB — small enough to double-buffer, big enough to feed the
-VPU. D is the lane dim (multiple of 128); the (1, BS) score row is VREG-wide.
+TPU tiling rule: a block's last two dims are multiples of (8, 128) or equal
+to the array's. So a scale tile is the block's whole (Hkv, bs) slab and the
+kernel reads its head's row, and the lengths column is a whole (R, 1) slab.
+The (1, bs) scale row scales the (R, bs) score / probability tiles rather
+than the (bs, D) payload, which would need the row moved onto sublanes.
 """
 from __future__ import annotations
 
@@ -23,52 +32,18 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 
-BS = 512  # cache tokens per tile
+BS = 512  # dense-cache tokens per tile
 
 _NEG = -1e30
+_HI = jax.lax.Precision.HIGHEST   # f32 dots stay f32 on the MXU
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, ns: int, scale: float):
-    s = pl.program_id(2)
-
-    @pl.when(s == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32)                      # (1, D)
-    k = k_ref[0, 0].astype(jnp.float32) * sk_ref[0, 0][..., None]  # (BS, D)
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale       # (1, BS)
-
-    pos = s * BS + jax.lax.broadcasted_iota(jnp.int32, (1, BS), 1)
-    valid = pos < len_ref[0]
-    scores = jnp.where(valid, scores, _NEG)
-
-    m_prev = m_ref[0, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(scores))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new) * valid.astype(jnp.float32)  # (1, BS)
-    l_ref[0, 0] = l_ref[0, 0] * corr + jnp.sum(p)
-    v = v_ref[0, 0].astype(jnp.float32) * sv_ref[0, 0][..., None]  # (BS, D)
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (1, D)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[0, 0] = m_new
-
-    @pl.when(s == ns - 1)
-    def _final():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[0, 0], 1e-20)).astype(o_ref.dtype)
-
-
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref,
-                  o_ref, m_ref, l_ref, acc_ref, *, bs: int, nt: int,
-                  scale: float):
-    b = pl.program_id(0)
+            m_ref, l_ref, acc_ref, *, bs: int, nt: int, scale: float):
+    """One cache tile of the online-softmax walk. Tile ``t`` covers
+    absolute positions [t*bs, (t+1)*bs) of the row's cache; q row r sees
+    positions < ``len_ref[0][r]``."""
+    h = pl.program_id(1)
     t = pl.program_id(2)
 
     @pl.when(t == 0)
@@ -77,26 +52,26 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                   # (Gp, D)
-    k = k_ref[0, 0].astype(jnp.float32) * sk_ref[0, 0][..., None]  # (bs, D)
+    s_k = sk_ref[0, pl.ds(h, 1), :]                       # (1, bs)
+    s_v = sv_ref[0, pl.ds(h, 1), :]
+    q = q_ref[0, 0].astype(jnp.float32)                   # (R, D)
     scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale       # (Gp, bs)
+        q, k_ref[0, 0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+        precision=_HI, preferred_element_type=jnp.float32)
+    scores = scores * s_k * scale                         # (R, bs)
 
-    # table entry t of this slot covers absolute positions [t*bs, (t+1)*bs);
-    # sentinel entries gather a clamped block whose tokens all land here
     pos = t * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    valid = pos < len_ref[b]                              # (1, bs) -> bcast
+    valid = pos < len_ref[0]                              # (R, bs)
     scores = jnp.where(valid, scores, _NEG)
 
-    m_prev = m_ref[...]                                   # (Gp, 1)
+    m_prev = m_ref[...]                                   # (R, 1)
     m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new) * valid.astype(jnp.float32)  # (Gp, bs)
+    p = jnp.exp(scores - m_new) * valid.astype(jnp.float32)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    v = v_ref[0, 0].astype(jnp.float32) * sv_ref[0, 0][..., None]  # (bs, D)
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)   # (Gp, D)
+    pv = jax.lax.dot_general(
+        p * s_v, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+        precision=_HI, preferred_element_type=jnp.float32)  # (R, D)
     acc_ref[...] = acc_ref[...] * corr + pv
     m_ref[...] = m_new
 
@@ -106,69 +81,101 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref,
                        jnp.maximum(l_ref[...], 1e-20)).astype(o_ref.dtype)
 
 
-def kvq_paged_decode_attn(q, k_pool, v_pool, s_k, s_v, block_tbl, lengths,
-                          interpret: bool = True):
-    """Block-table flash-decode over a paged int8/int4 KV pool.
+def _paged_kernel(tbl_ref, *refs, **kw):
+    _kernel(*refs, **kw)    # the table only steers the index maps
 
-    Same online-softmax walk as the dense kernel, but the grid's innermost
-    dim walks the slot's *block table* instead of a contiguous cache stripe:
-    the table rides in as a scalar-prefetch operand so the K/V BlockSpec
-    index maps can turn (slot, table index) into a pool block id before the
-    tile DMA is issued. Sentinel entries must be clamped to NB-1 by the
-    caller (ops.py); their scores are masked by ``lengths``.
 
-    TPU tiling: the grid is (B, Hkv, T) — one step per *KV* head — and the
-    q operand arrives pre-grouped as (B, Hkv, Gp, D), all of a KV head's
-    query heads stacked on the sublane axis (ops.py pads the GQA group to
-    Gp, a multiple of 8 f32 sublanes). Each int8 (bs, D) K/V tile is
-    therefore fetched once per KV head instead of once per *query* head
-    (``group``x less pool HBM traffic), score/accumulator tiles are
-    (Gp, bs)/(Gp, D) full-sublane VREGs rather than 1-row slivers, and the
-    (1, bs) f32 scale tiles amortize the same way (lane-width at bs=128;
-    ops.py requires bs >= 32 on real hardware so every tile meets the int8
-    32-sublane minimum).
+def _scratch(rows: int, D: int):
+    return [pltpu.VMEM((rows, 1), jnp.float32),   # running max
+            pltpu.VMEM((rows, 1), jnp.float32),   # running denom
+            pltpu.VMEM((rows, D), jnp.float32)]   # output accumulator
 
-    q (B,Hkv,Gp,D) pre-grouped; pools (NB,Hkv,bs,D) int8; scales
-    (NB,Hkv,bs) fp32; block_tbl (B,T) int32 (clamped); lengths (B,) int32.
-    Returns (B,Hkv,Gp,D); rows past the real group size are garbage and
-    sliced off by the wrapper.
+
+def kvq_decode_attn(q, k_q, v_q, s_k, s_v, lengths, interpret: bool = True):
+    """Flash-decode over a dense per-slot int cache.
+
+    q (B, Hkv, R, D) pre-grouped; k_q/v_q (B, Hkv, S, D) int8 with S a
+    multiple of BS; s_k/s_v (B, Hkv, S) f32; lengths (B, R, 1) int32.
+    Returns (B, Hkv, R, D) in q.dtype.
     """
-    B, Hkv, Gp, D = q.shape
+    B, Hkv, R, D = q.shape
+    ns = k_q.shape[2] // BS
+    kv_ix = lambda b, h, s: (b, h, s, 0)
+    sc_ix = lambda b, h, s: (b, 0, s)
+    row_ix = lambda b, h, s: (b, h, 0, 0)
+    return pl.pallas_call(
+        functools.partial(_kernel, bs=BS, nt=ns, scale=1.0 / D ** 0.5),
+        grid=(B, Hkv, ns),
+        in_specs=[
+            pl.BlockSpec((1, R, 1), lambda b, h, s: (b, 0, 0)),  # lengths
+            pl.BlockSpec((1, 1, R, D), row_ix),                  # q
+            pl.BlockSpec((1, 1, BS, D), kv_ix),                  # k
+            pl.BlockSpec((1, 1, BS, D), kv_ix),                  # v
+            pl.BlockSpec((1, Hkv, BS), sc_ix),                   # s_k
+            pl.BlockSpec((1, Hkv, BS), sc_ix),                   # s_v
+        ],
+        out_specs=pl.BlockSpec((1, 1, R, D), row_ix),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=_scratch(R, D),
+        interpret=interpret,
+    )(lengths, q, k_q, v_q, s_k, s_v)
+
+
+def kvq_paged_attn(q, k_pool, v_pool, s_k, s_v, block_tbl, lengths,
+                   interpret: bool = True):
+    """Block-table flash attention over a paged int8/int4 KV pool: the
+    decode step (one query per slot) and the speculative verify-wave (the
+    ``k + 1`` window queries of a slot, in ONE walk of its table).
+
+    The grid's innermost dim walks the slot's *block table* instead of a
+    contiguous cache stripe: the table rides in as a scalar-prefetch
+    operand so the K/V BlockSpec index maps turn (slot, table index) into
+    a pool block id before the tile DMA is issued. Sentinel entries must
+    be clamped to NB-1 by the caller (ops.py); their positions are masked
+    by ``lengths``.
+
+    q (B, Hkv, R, D) pre-grouped; pools (NB, Hkv, bs, D) int8; scales
+    (NB, Hkv, bs) f32; block_tbl (B, T) int32 (clamped); lengths (B, R, 1)
+    int32. Returns (B, Hkv, R, D) in q.dtype.
+    """
+    B, Hkv, R, D = q.shape
     bs = k_pool.shape[2]
     T = block_tbl.shape[1]
-    scale = 1.0 / (D ** 0.5)
-    kv_ix = lambda b, h, t, tbl, lens: (tbl[b, t], h, 0, 0)
-    sc_ix = lambda b, h, t, tbl, lens: (tbl[b, t], h, 0)
+    kv_ix = lambda b, h, t, tbl: (tbl[b, t], h, 0, 0)
+    sc_ix = lambda b, h, t, tbl: (tbl[b, t], 0, 0)
+    row_ix = lambda b, h, t, tbl: (b, h, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                       # block_tbl, lengths
+        num_scalar_prefetch=1,                       # block_tbl
         grid=(B, Hkv, T),
         in_specs=[
-            pl.BlockSpec((1, 1, Gp, D),
-                         lambda b, h, t, tbl, lens: (b, h, 0, 0)),
+            pl.BlockSpec((1, R, 1), lambda b, h, t, tbl: (b, 0, 0)),
+            pl.BlockSpec((1, 1, R, D), row_ix),      # q
             pl.BlockSpec((1, 1, bs, D), kv_ix),      # k pool
             pl.BlockSpec((1, 1, bs, D), kv_ix),      # v pool
-            pl.BlockSpec((1, 1, bs), sc_ix),         # s_k pool
-            pl.BlockSpec((1, 1, bs), sc_ix),         # s_v pool
+            pl.BlockSpec((1, Hkv, bs), sc_ix),       # s_k pool
+            pl.BlockSpec((1, Hkv, bs), sc_ix),       # s_v pool
         ],
-        out_specs=pl.BlockSpec((1, 1, Gp, D), lambda b, h, t, tbl, lens:
-                               (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Gp, 1), jnp.float32),  # running max
-            pltpu.VMEM((Gp, 1), jnp.float32),  # running denom
-            pltpu.VMEM((Gp, D), jnp.float32),  # output accumulator
-        ],
+        out_specs=pl.BlockSpec((1, 1, R, D), row_ix),
+        scratch_shapes=_scratch(R, D),
     )
     return pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, nt=T, scale=scale),
+        functools.partial(_paged_kernel, bs=bs, nt=T, scale=1.0 / D ** 0.5),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, Gp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(block_tbl, lengths, q, k_pool, v_pool, s_k, s_v)
 
 
 def _gather_dequant_kernel(tbl_ref, kq_ref, sk_ref, o_ref):
-    o_ref[0, 0, 0] = (kq_ref[0, 0].astype(jnp.float32)
-                      * sk_ref[0, 0][..., None])
+    bs = kq_ref.shape[2]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1))
+    for h in range(kq_ref.shape[1]):
+        # the head's (1, bs) scale row as a (bs, 1) column: a masked lane
+        # sum with one nonzero term per row, so exact
+        col = jnp.sum(jnp.where(eye, sk_ref[0, h:h + 1, :], 0.0), axis=1,
+                      keepdims=True)
+        o_ref[0, h, 0] = kq_ref[0, h].astype(jnp.float32) * col
 
 
 def gather_dequant_paged_kv(pool, s_pool, block_tbl, interpret: bool = True):
@@ -177,11 +184,11 @@ def gather_dequant_paged_kv(pool, s_pool, block_tbl, interpret: bool = True):
     The tail-wave history read: the XLA path gathers the int8 pool and the
     scale pool separately, materializing an int8 copy of every history
     block in HBM before a second dequantize pass re-reads it. Here one
-    grid step per (row, head, table entry) DMAs the (bs, D) int8 tile and
-    its (bs,) scale straight into VMEM and writes only the dequantized f32
-    tile back — the int8 intermediate never exists in HBM. Sentinel table
-    entries must be clamped by the caller (ops.py); callers mask their
-    positions exactly as they do for the XLA gather.
+    grid step per (row, table entry) DMAs the block's (Hkv, bs, D) int8
+    payload and its (Hkv, bs) scales straight into VMEM and writes only
+    the dequantized f32 tiles back — the int8 intermediate never exists in
+    HBM. Sentinel table entries must be clamped by the caller (ops.py);
+    callers mask their positions exactly as they do for the XLA gather.
 
     pool (NB, Hkv, bs, D) int8; s_pool (NB, Hkv, bs) f32; block_tbl (n, T)
     int32 (clamped). Returns (n, Hkv, T*bs, D) f32 — identical layout and
@@ -190,17 +197,16 @@ def gather_dequant_paged_kv(pool, s_pool, block_tbl, interpret: bool = True):
     """
     NB, Hkv, bs, D = pool.shape
     n, T = block_tbl.shape
-    kv_ix = lambda r, h, t, tbl: (tbl[r, t], h, 0, 0)
-    sc_ix = lambda r, h, t, tbl: (tbl[r, t], h, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                       # block_tbl
-        grid=(n, Hkv, T),
+        grid=(n, T),
         in_specs=[
-            pl.BlockSpec((1, 1, bs, D), kv_ix),
-            pl.BlockSpec((1, 1, bs), sc_ix),
+            pl.BlockSpec((1, Hkv, bs, D), lambda r, t, tbl: (tbl[r, t], 0, 0,
+                                                             0)),
+            pl.BlockSpec((1, Hkv, bs), lambda r, t, tbl: (tbl[r, t], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, bs, D),
-                               lambda r, h, t, tbl: (r, h, t, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, 1, bs, D),
+                               lambda r, t, tbl: (r, 0, t, 0, 0)),
     )
     out = pl.pallas_call(
         _gather_dequant_kernel,
@@ -209,96 +215,6 @@ def gather_dequant_paged_kv(pool, s_pool, block_tbl, interpret: bool = True):
         interpret=interpret,
     )(block_tbl, pool, s_pool)
     return out.reshape(n, Hkv, T * bs, D)
-
-
-def _spec_verify_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, sk_ref,
-                        sv_ref, o_ref, m_ref, l_ref, acc_ref, *, bs: int,
-                        nt: int, scale: float):
-    t = pl.program_id(2)
-
-    @pl.when(t == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, :, 0].astype(jnp.float32)                # (C, D)
-    k = k_ref[0, 0].astype(jnp.float32) * sk_ref[0, 0][..., None]  # (bs, D)
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale       # (C, bs)
-
-    # query row c of this slot sees cache positions < len[b, c] — the
-    # shared history plus the window prefix through itself, all already
-    # committed to the pool by the wave's scatter
-    pos = t * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    valid = pos < len_ref[0][:, None]                     # (C, bs)
-    scores = jnp.where(valid, scores, _NEG)
-
-    m_prev = m_ref[...]                                   # (C, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new) * valid.astype(jnp.float32)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    v = v_ref[0, 0].astype(jnp.float32) * sv_ref[0, 0][..., None]  # (bs, D)
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)   # (C, D)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[...] = m_new
-
-    @pl.when(t == nt - 1)
-    def _final():
-        o_ref[0, :, 0] = (acc_ref[...] /
-                          jnp.maximum(l_ref[...], 1e-20)).astype(o_ref.dtype)
-
-
-def kvq_spec_verify_attn(q, k_pool, v_pool, s_k, s_v, block_tbl, lengths,
-                         interpret: bool = True):
-    """Block-table flash attention for C verify queries per slot.
-
-    The speculative verify-wave's attention: the paged flash-decode walk
-    (grid (B, H, T), table as a scalar-prefetch operand) widened to a
-    (C, bs) score tile so ONE pass over each slot's block table serves
-    all ``C = k + 1`` window positions — instead of C separate decode
-    calls re-streaming the same int8 blocks from HBM. Per-query masking
-    comes from ``lengths`` (B, C) riding along as a VMEM operand.
-
-    q (B, C, H, D); pools (NB, Hkv, bs, D) int8; scales (NB, Hkv, bs)
-    fp32; block_tbl (B, T) int32 (sentinels clamped by the caller);
-    lengths (B, C) int32. Returns (B, C, H, D) in q.dtype.
-    """
-    B, C, H, D = q.shape
-    Hkv, bs = k_pool.shape[1], k_pool.shape[2]
-    T = block_tbl.shape[1]
-    group = H // Hkv
-    scale = 1.0 / (D ** 0.5)
-    kv_ix = lambda b, h, t, tbl: (tbl[b, t], h // group, 0, 0)
-    sc_ix = lambda b, h, t, tbl: (tbl[b, t], h // group, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                           # block_tbl
-        grid=(B, H, T),
-        in_specs=[
-            pl.BlockSpec((1, C), lambda b, h, t, tbl: (b, 0)),   # lengths
-            pl.BlockSpec((1, C, 1, D), lambda b, h, t, tbl: (b, 0, h, 0)),
-            pl.BlockSpec((1, 1, bs, D), kv_ix),          # k pool
-            pl.BlockSpec((1, 1, bs, D), kv_ix),          # v pool
-            pl.BlockSpec((1, 1, bs), sc_ix),             # s_k pool
-            pl.BlockSpec((1, 1, bs), sc_ix),             # s_v pool
-        ],
-        out_specs=pl.BlockSpec((1, C, 1, D),
-                               lambda b, h, t, tbl: (b, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((C, 1), jnp.float32),   # running max
-            pltpu.VMEM((C, 1), jnp.float32),   # running denom
-            pltpu.VMEM((C, D), jnp.float32),   # output accumulator
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_spec_verify_kernel, bs=bs, nt=T, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, D), q.dtype),
-        interpret=interpret,
-    )(block_tbl, lengths, q, k_pool, v_pool, s_k, s_v)
 
 
 def _copy_kernel(src_ref, dst_ref, x_ref, o_ref):
@@ -311,20 +227,23 @@ def pool_block_copy(x, src, dst, interpret: bool = True):
     The copy-on-write primitive of the prefix-shared paged cache: when a
     slot must write into a block another slot still maps, the engine clones
     the int8 payload (+ scales) device-side and repoints the writer's table
-    entry at the clone. ``x`` (rep, NB, X) is the layer-stacked pool with
-    the per-block payload flattened to the lane dim; the pool is aliased
-    into the output so only the ``dst`` blocks are rewritten — one block
-    DMA per (layer, pair) grid step, no full-pool traffic. Pairs with
-    ``src == dst`` are self-copy no-ops (the padding convention ops.py uses
-    to bound compile variants).
+    entry at the clone. ``x`` (rep, NB, ...) is a layer-stacked pool leaf;
+    a block is everything past the NB axis, so its last two dims are the
+    array's. The pool is aliased into the output so only the ``dst``
+    blocks are rewritten — one block DMA per (layer, pair) grid step, no
+    full-pool traffic. Pairs with ``src == dst`` are self-copy no-ops (the
+    padding convention ops.py uses to bound compile variants).
     """
-    rep, _nb, X = x.shape
+    rep, _nb, *blk = x.shape
     n = src.shape[0]
+    tail = (0,) * len(blk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                       # src ids, dst ids
         grid=(rep, n),
-        in_specs=[pl.BlockSpec((1, 1, X), lambda r, i, s, d: (r, s[i], 0))],
-        out_specs=pl.BlockSpec((1, 1, X), lambda r, i, s, d: (r, d[i], 0)),
+        in_specs=[pl.BlockSpec((1, 1, *blk),
+                               lambda r, i, s, d: (r, s[i], *tail))],
+        out_specs=pl.BlockSpec((1, 1, *blk),
+                               lambda r, i, s, d: (r, d[i], *tail)),
     )
     return pl.pallas_call(
         _copy_kernel,
@@ -333,35 +252,3 @@ def pool_block_copy(x, src, dst, interpret: bool = True):
         input_output_aliases={2: 0},                 # pool is updated in place
         interpret=interpret,
     )(src, dst, x)
-
-
-def kvq_decode_attn(q, k_q, v_q, s_k, s_v, lengths,
-                    interpret: bool = True):
-    """See ref.py for shapes; S must be a multiple of BS (ops.py pads)."""
-    B, H, D = q.shape
-    Hkv, S = k_q.shape[1], k_q.shape[2]
-    group = H // Hkv
-    ns = S // BS
-    scale = 1.0 / (D ** 0.5)
-    kv_ix = lambda b, h, s: (b, h // group, s, 0)
-    sc_ix = lambda b, h, s: (b, h // group, s)
-    return pl.pallas_call(
-        functools.partial(_kernel, ns=ns, scale=scale),
-        grid=(B, H, ns),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, s: (b,)),           # lengths
-            pl.BlockSpec((1, 1, D), lambda b, h, s: (b, h, 0)),  # q
-            pl.BlockSpec((1, 1, BS, D), kv_ix),                  # k
-            pl.BlockSpec((1, 1, BS, D), kv_ix),                  # v
-            pl.BlockSpec((1, 1, BS), sc_ix),                     # s_k
-            pl.BlockSpec((1, 1, BS), sc_ix),                     # s_v
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, s: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running denom
-            pltpu.VMEM((1, D), jnp.float32),   # output accumulator
-        ],
-        interpret=interpret,
-    )(lengths, q, k_q, v_q, s_k, s_v)

@@ -1,11 +1,17 @@
-"""jit'd wrapper for the quantized-KV flash-decode kernel."""
+"""jit-side wrappers for the quantized-KV kernels: dense and paged
+flash-decode, spec-verify, gather-dequant and the pool's block copy. They
+pad and regroup operands into the kernels' layouts, and on a serving mesh
+run each kernel per device."""
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.kernels import head_axis, per_device
 from repro.kernels.kvq_attn import kernel as K
 from repro.kernels.kvq_attn.ref import (chunk_commit_ids, copy_pool_blocks_ref,
                                         gather_paged_kv,
@@ -49,8 +55,8 @@ def commit_chunk_kv(cache: dict, k_q, v_q, s_k, s_v, block_tbl,
     return new
 
 
-def copy_pool_blocks(pool, src, dst,
-                     use_pallas: Optional[bool] = None) -> jnp.ndarray:
+def copy_pool_blocks(pool, src, dst, use_pallas: Optional[bool] = None,
+                     mesh=None) -> jnp.ndarray:
     """Device-side copy-on-write block clone over a layer-stacked pool leaf.
 
     pool (rep, NB, ...) int8 payload or fp32 scales; src/dst (n,) int32
@@ -58,7 +64,8 @@ def copy_pool_blocks(pool, src, dst,
     the pair count to a power of two to bound compile variants) and are
     dropped. On TPU the Pallas kernel rewrites only the ``dst`` blocks via
     an aliased in-place pallas_call; elsewhere the XLA scatter reference
-    runs (bitwise-identical result).
+    runs (bitwise-identical result). On a ``mesh`` each device copies the
+    blocks of its own KV-head shard.
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
@@ -73,13 +80,14 @@ def copy_pool_blocks(pool, src, dst,
     # write it back after the real copy landed).
     srcp = jnp.where(pad, src[0], src).astype(jnp.int32)
     dstp = jnp.where(pad, src[0], dst).astype(jnp.int32)
-    flat = pool.reshape(pool.shape[0], nb, -1)
-    out = K.pool_block_copy(flat, srcp, dstp, interpret=_INTERPRET)
-    return out.reshape(pool.shape)
+    spec = P(None, None, head_axis(mesh, pool.shape[2]))
+    copy = functools.partial(K.pool_block_copy, interpret=_INTERPRET)
+    return per_device(copy, mesh, (spec, P(), P()), spec)(pool, srcp, dstp)
 
 
 def gather_dequant_paged_kv(pool, s_pool, block_tbl,
-                            use_pallas: Optional[bool] = None) -> jnp.ndarray:
+                            use_pallas: Optional[bool] = None,
+                            mesh=None) -> jnp.ndarray:
     """Dequantized history gather for the batched tail/chunk prefill wave.
 
     pool (NB, Hkv, bs, D) int8; s_pool (NB, Hkv, bs) fp32; block_tbl (n, T)
@@ -95,12 +103,46 @@ def gather_dequant_paged_kv(pool, s_pool, block_tbl,
                 * gather_paged_kv(s_pool, block_tbl)[..., None])
     nb = pool.shape[0]
     tbl = jnp.minimum(block_tbl.astype(jnp.int32), nb - 1)
-    return K.gather_dequant_paged_kv(pool, s_pool.astype(jnp.float32), tbl,
-                                     interpret=_INTERPRET)
+    heads = P(None, head_axis(mesh, pool.shape[1]))
+    gather = functools.partial(K.gather_dequant_paged_kv,
+                               interpret=_INTERPRET)
+    return per_device(gather, mesh, (heads, heads, P()), heads)(
+        pool, s_pool.astype(jnp.float32), tbl)
+
+
+def _group_rows(q, lengths, Hkv: int):
+    """(B, C, H, D) queries with (B, C) valid extents -> the kernels'
+    grouped operands q (B, Hkv, R, D) and lengths (B, R, 1).
+
+    The R rows of KV head n are its GQA group's query heads times the C
+    window positions (row ``g * C + c`` for head ``n * group + g``),
+    padded to a multiple of 8 f32 sublanes. Pad rows have q = 0 and
+    length 0, so every position masks out and they reduce to exact zeros
+    (no NaN: the final divide clamps the denominator)."""
+    B, C, H, D = q.shape
+    group = H // Hkv
+    R = group * C
+    Rp = -(-R // 8) * 8
+    qg = q.reshape(B, C, Hkv, group, D).transpose(0, 2, 3, 1, 4)
+    qg = qg.reshape(B, Hkv, R, D)
+    lens = jnp.broadcast_to(lengths.astype(jnp.int32)[:, None, :],
+                            (B, group, C)).reshape(B, R, 1)
+    if Rp != R:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
+        lens = jnp.pad(lens, ((0, 0), (0, Rp - R), (0, 0)))
+    return qg, lens
+
+
+def _ungroup_rows(out, C: int, H: int):
+    """Inverse of :func:`_group_rows` on the kernel output."""
+    B, Hkv, _, D = out.shape
+    group = H // Hkv
+    out = out[:, :, :group * C].reshape(B, Hkv, group, C, D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, D)
 
 
 def kvq_decode_attn(q, k_q, v_q, s_k, s_v, lengths,
-                    use_pallas: bool = True) -> jnp.ndarray:
+                    use_pallas: bool = True, mesh=None) -> jnp.ndarray:
     """Decode attention over an integer cache; pads S to tile multiples.
 
     q (B,H,D); k_q/v_q (B,Hkv,S,D) int8; s_k/s_v (B,Hkv,S) fp32;
@@ -117,77 +159,70 @@ def kvq_decode_attn(q, k_q, v_q, s_k, s_v, lengths,
         pads = ((0, 0), (0, 0), (0, pad))
         s_k = jnp.pad(s_k, pads)
         s_v = jnp.pad(s_v, pads)
-    return K.kvq_decode_attn(q, k_q, v_q, s_k.astype(jnp.float32),
-                             s_v.astype(jnp.float32),
-                             lengths.astype(jnp.int32), interpret=_INTERPRET)
+
+    def run(q, k_q, v_q, s_k, s_v, lengths):
+        qg, lens = _group_rows(q[:, None], lengths[:, None], k_q.shape[1])
+        out = K.kvq_decode_attn(qg, k_q, v_q, s_k, s_v, lens,
+                                interpret=_INTERPRET)
+        return _ungroup_rows(out, 1, q.shape[1])[:, 0]
+
+    heads = P(None, head_axis(mesh, k_q.shape[1]))
+    return per_device(run, mesh, (heads,) * 5 + (P(),), heads)(
+        q, k_q, v_q, s_k.astype(jnp.float32), s_v.astype(jnp.float32),
+        lengths)
+
+
+def _paged_attn(q, k_pool, v_pool, s_k, s_v, block_tbl, lengths, mesh):
+    """q (B, C, H, D), lengths (B, C) through the block-table kernel."""
+    nb, Hkv, bs = k_pool.shape[:3]
+    if not _INTERPRET and bs < 32:
+        # an int8 (bs, D) K/V tile must cover the 32-sublane int8 tile
+        raise ValueError(f"the paged attention kernel needs block_size >= "
+                         f"32 on TPU, got {bs}")
+
+    def run(q, k_pool, v_pool, s_k, s_v, tbl, lengths):
+        qg, lens = _group_rows(q, lengths, k_pool.shape[1])
+        out = K.kvq_paged_attn(qg, k_pool, v_pool, s_k, s_v, tbl, lens,
+                               interpret=_INTERPRET)
+        return _ungroup_rows(out, q.shape[1], q.shape[2])
+
+    ax = head_axis(mesh, Hkv)
+    pool, qs = P(None, ax), P(None, None, ax)
+    return per_device(run, mesh, (qs,) + (pool,) * 4 + (P(), P()), qs)(
+        q, k_pool, v_pool, s_k.astype(jnp.float32), s_v.astype(jnp.float32),
+        jnp.minimum(block_tbl.astype(jnp.int32), nb - 1), lengths)
 
 
 def kvq_spec_verify_attn(q, k_pool, v_pool, s_k, s_v, block_tbl, lengths,
-                         use_pallas: bool = True) -> jnp.ndarray:
+                         use_pallas: bool = True, mesh=None) -> jnp.ndarray:
     """Multi-query block-table attention for the speculative verify-wave.
 
     q (B, C, H, D): the wave's C window queries per slot (their K/V are
     already committed to the pool); block_tbl (B, T) int32 (sentinels
     clamped here); lengths (B, C) per-query valid extents. On TPU the
-    widened Pallas kernel serves all C queries in one table walk;
-    elsewhere the gather + per-position decode oracle runs (bitwise
-    identical to C sequential decode steps).
+    block-table kernel serves all C queries in one table walk; elsewhere
+    the gather + per-position decode oracle runs (bitwise identical to C
+    sequential decode steps).
     """
     if not use_pallas:
         return kvq_spec_verify_attn_ref(q, k_pool, v_pool, s_k, s_v,
                                         block_tbl, lengths)
-    nb = k_pool.shape[0]
-    tbl = jnp.minimum(block_tbl.astype(jnp.int32), nb - 1)
-    # pad the query-window axis to a full f32 sublane tile: C = k + 1 is
-    # small (2-16), and an unpadded C leaves the (C, bs) score tile and the
-    # (C, D) accumulator scratch on partial sublanes. Padded rows have q = 0
-    # and length 0, so every position masks out and they reduce to exact
-    # zeros (no NaN: the final divide clamps the denominator).
-    C = q.shape[1]
-    Cp = -(-C // 8) * 8
-    if Cp != C:
-        q = jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
-        lengths = jnp.pad(lengths, ((0, 0), (0, Cp - C)))
-    out = K.kvq_spec_verify_attn(q, k_pool, v_pool,
-                                 s_k.astype(jnp.float32),
-                                 s_v.astype(jnp.float32), tbl,
-                                 lengths.astype(jnp.int32),
-                                 interpret=_INTERPRET)
-    return out[:, :C] if Cp != C else out
+    return _paged_attn(q, k_pool, v_pool, s_k, s_v, block_tbl, lengths,
+                       mesh)
 
 
 def kvq_paged_decode_attn(q, k_pool, v_pool, s_k, s_v, block_tbl, lengths,
-                          use_pallas: bool = True) -> jnp.ndarray:
+                          use_pallas: bool = True, mesh=None) -> jnp.ndarray:
     """Block-table decode attention over a paged integer cache pool.
 
     q (B,H,D); k_pool/v_pool (NB,Hkv,bs,D) int8; s_k/s_v (NB,Hkv,bs) fp32;
     block_tbl (B,T) int32 (entries >= NB are unallocated sentinels, clamped
-    here); lengths (B,) int32 tokens resident per slot.
-
-    The kernel grid runs per *KV* head with the GQA group stacked on the
-    q sublane axis (see kernel.py): q is regrouped (B, Hkv, group, D) and
-    the group padded to a multiple of 8 sublanes here. Real hardware also
-    needs the int8 (bs, D) K/V tiles to cover >= 32 sublanes, so bs < 32
-    falls back to the XLA reference off the interpreter (bitwise-identical
-    result; interpret mode still exercises the kernel at any bs so the
-    parity tests run everywhere).
+    here); lengths (B,) int32 tokens resident per slot. The verify-wave's
+    kernel with a one-query window. On TPU the pool's block size must be
+    >= 32 (the int8 tile); interpret mode runs the kernel at any size.
     """
-    if use_pallas and not _INTERPRET and k_pool.shape[2] < 32:
-        use_pallas = False
     if not use_pallas:
         return kvq_paged_decode_attn_ref(q, k_pool, v_pool, s_k, s_v,
                                          block_tbl, lengths)
-    nb, Hkv = k_pool.shape[0], k_pool.shape[1]
-    B, H, D = q.shape
-    group = H // Hkv
-    Gp = -(-group // 8) * 8
-    qg = q.reshape(B, Hkv, group, D)   # head h -> (h // group, h % group)
-    if Gp != group:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - group), (0, 0)))
-    tbl = jnp.minimum(block_tbl.astype(jnp.int32), nb - 1)
-    out = K.kvq_paged_decode_attn(qg, k_pool, v_pool,
-                                  s_k.astype(jnp.float32),
-                                  s_v.astype(jnp.float32), tbl,
-                                  lengths.astype(jnp.int32),
-                                  interpret=_INTERPRET)
-    return out[:, :, :group].reshape(B, H, D)
+    return _paged_attn(q[:, None], k_pool, v_pool, s_k, s_v, block_tbl,
+                       lengths[:, None], mesh)[:, 0]

@@ -2,7 +2,9 @@
 
 The deployment (serving) hot path. TPU-native design:
 * weights stored HBM-packed (two int4 per byte) -> 2x less HBM traffic than
-  int8, 4x less than bf16; nibbles are unpacked in VMEM registers,
+  int8, 4x less than bf16; nibbles are unpacked in VMEM registers into a
+  low (even-channel) and a high (odd-channel) plane, each contracted
+  against the matching half of the activations, which arrive pre-split,
 * the MXU consumes int8 x int8 -> int32 accumulation
   (``preferred_element_type=int32``),
 * per-token activation scale (M, 1) and per-output-channel weight scale (N,)
@@ -10,7 +12,7 @@ The deployment (serving) hot path. TPU-native design:
   fused with the optional bias add.
 
 Grid (M/bm, N/bn, K/bk), K innermost for accumulation in VMEM scratch.
-Tiles: bm=256, bn=256, bk=512 -> x tile 128 KiB int8, packed w tile 64 KiB,
+Tiles: bm=256, bn=256, bk=512 -> x tiles 2x64 KiB int8, packed w tile 64 KiB,
 acc 256 KiB int32; MXU dims all multiples of 128.
 """
 from __future__ import annotations
@@ -25,17 +27,19 @@ import jax.numpy as jnp
 BM, BN, BK = 256, 256, 512
 
 
-def _unpack_nibbles(p: jnp.ndarray) -> jnp.ndarray:
-    """(n, k/2) uint8 -> (n, k) int8 in [-8, 7]; interleaved layout."""
-    lo = (p & 0xF).astype(jnp.int8)
-    hi = ((p >> 4) & 0xF).astype(jnp.int8)
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    out = jnp.stack([lo, hi], axis=-1)                # (n, k/2, 2)
-    return out.reshape(p.shape[0], p.shape[1] * 2)
+def _unpack_nibbles(p: jnp.ndarray):
+    """(n, k/2) packed uint8 -> (lo, hi) int8 planes in [-8, 7].
+
+    ``lo`` holds the even input channels, ``hi`` the odd ones. Shifting the
+    nibble to the top of an int32 and back sign-extends it; the planes stay
+    (n, k/2), so no vector shape cast is needed to feed the MXU."""
+    w = p.astype(jnp.int32)
+    lo = (w << 28) >> 28
+    hi = (w << 24) >> 28
+    return lo.astype(jnp.int8), hi.astype(jnp.int8)
 
 
-def _kernel(x_ref, wp_ref, sx_ref, sw_ref, b_ref, o_ref, acc_ref, *,
+def _kernel(xe_ref, xo_ref, wp_ref, sx_ref, sw_ref, b_ref, o_ref, acc_ref, *,
             nk: int, has_bias: bool):
     k = pl.program_id(2)
 
@@ -43,11 +47,13 @@ def _kernel(x_ref, wp_ref, sx_ref, sw_ref, b_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _unpack_nibbles(wp_ref[...])                  # (BN, BK) int8
-    x = x_ref[...]                                    # (BM, BK) int8
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),               # contract K with K
-        preferred_element_type=jnp.int32)
+    lo, hi = _unpack_nibbles(wp_ref[...])             # (BN, BK/2) int8 each
+    nt = (((1,), (1,)), ((), ()))                     # contract K with K
+    acc_ref[...] += (
+        jax.lax.dot_general(xe_ref[...], lo, nt,
+                            preferred_element_type=jnp.int32)
+        + jax.lax.dot_general(xo_ref[...], hi, nt,
+                              preferred_element_type=jnp.int32))
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -59,16 +65,20 @@ def _kernel(x_ref, wp_ref, sx_ref, sw_ref, b_ref, o_ref, acc_ref, *,
         o_ref[...] = y.astype(o_ref.dtype)
 
 
-def w4a8_matmul(x_q: jnp.ndarray, w_packed: jnp.ndarray, s_x: jnp.ndarray,
-                s_w: jnp.ndarray, bias: jnp.ndarray | None = None,
-                out_dtype=jnp.bfloat16, interpret: bool = True) -> jnp.ndarray:
-    """x_q: (M, K) int8; w_packed: (N, K/2) uint8; s_x: (M, 1); s_w: (1, N).
+def w4a8_matmul(x_even: jnp.ndarray, x_odd: jnp.ndarray,
+                w_packed: jnp.ndarray, s_x: jnp.ndarray, s_w: jnp.ndarray,
+                bias: jnp.ndarray | None = None, out_dtype=jnp.bfloat16,
+                interpret: bool = True) -> jnp.ndarray:
+    """x_even/x_odd: (M, K/2) int8, the even and odd input channels of the
+    activations (ops.py splits them); w_packed: (N, K/2) uint8, byte j
+    packing channels 2j (low nibble) and 2j+1 (high); s_x: (M, 1);
+    s_w: (1, N).
 
     All dims must be tile multiples (ops.py pads).
     """
-    M, K = x_q.shape
+    M, Kh = x_even.shape
     N = w_packed.shape[0]
-    nk = K // BK
+    nk = 2 * Kh // BK
     has_bias = bias is not None
     if bias is None:
         bias = jnp.zeros((1, N), jnp.float32)
@@ -77,7 +87,8 @@ def w4a8_matmul(x_q: jnp.ndarray, w_packed: jnp.ndarray, s_x: jnp.ndarray,
         functools.partial(_kernel, nk=nk, has_bias=has_bias),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((BM, BK), lambda i, j, k: (i, k)),
+            pl.BlockSpec((BM, BK // 2), lambda i, j, k: (i, k)),
+            pl.BlockSpec((BM, BK // 2), lambda i, j, k: (i, k)),
             pl.BlockSpec((BN, BK // 2), lambda i, j, k: (j, k)),
             pl.BlockSpec((BM, 1), lambda i, j, k: (i, 0)),
             pl.BlockSpec((1, BN), lambda i, j, k: (0, j)),
@@ -87,4 +98,4 @@ def w4a8_matmul(x_q: jnp.ndarray, w_packed: jnp.ndarray, s_x: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((BM, BN), jnp.int32)],
         interpret=interpret,
-    )(x_q, w_packed, s_x, s_w, bias)
+    )(x_even, x_odd, w_packed, s_x, s_w, bias)

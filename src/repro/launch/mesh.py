@@ -10,6 +10,7 @@ jax device state (the dry-run pins the device count before first jax use).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +23,8 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, have {len(devices)} — "
             "run under dryrun.py (it sets xla_force_host_platform_device_count)")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return jax.make_mesh(shape, axes, devices=devices[:need],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_local_mesh(model_parallel: int = 1):
@@ -34,5 +36,7 @@ def make_local_mesh(model_parallel: int = 1):
             f"count ({n} available) — force more host devices with "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N or pick "
             "a TP degree that divides the machine")
+    # Auto axes: GSPMD propagates shardings through the serve waves; the
+    # default Explicit axes would demand an out_sharding on every gather
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"), axis_types=(AxisType.Auto,) * 2)

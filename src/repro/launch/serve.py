@@ -23,6 +23,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_reduced_config
+from repro.launch.cache import enable_compilation_cache
 from repro.models import init_params
 from repro.serve.engine import Request, ServeEngine
 
@@ -260,6 +261,7 @@ def main():
     ap.add_argument("--bench-out", default="",
                     help="write the run's stats to this JSON file")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     cfg = (get_config if args.full else get_reduced_config)(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))

@@ -27,6 +27,7 @@ from repro.core.distill import next_token_loss
 from repro.core.precision import parse_policy
 from repro.core.qat import calibrate_weight_scales, make_ctx, merge_act_scales
 from repro.data import MixtureIterator, SyntheticConfig, calibration_batches
+from repro.launch.cache import enable_compilation_cache
 from repro.launch.steps import make_train_step, _text_logits
 from repro.models import forward, init_params
 from repro.optim import adamw_init, adamw_update, cosine_schedule
@@ -155,6 +156,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--simulate-failure-at", type=int, default=-1)
     args = ap.parse_args()
+    enable_compilation_cache()
     tcfg = TrainConfig(precision=args.precision, total_steps=args.steps,
                        ref_steps=args.steps, batch_size=args.batch_size,
                        seq_len=args.seq_len)

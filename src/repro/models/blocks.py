@@ -27,7 +27,7 @@ MOE_CAPACITY_FACTOR = 1.25
 MOE_CHUNK_S = 1024      # sequence-chunk for the dispatch working set
 
 
-def _decode_attn(q, k_q, v_q, s_k, s_v, lengths) -> jnp.ndarray:
+def _decode_attn(q, k_q, v_q, s_k, s_v, lengths, mesh=None) -> jnp.ndarray:
     """Decode attention over the int cache for a full slot batch.
 
     On TPU this is the Pallas flash-decode kernel (int8 tiles dequantized
@@ -37,12 +37,12 @@ def _decode_attn(q, k_q, v_q, s_k, s_v, lengths) -> jnp.ndarray:
     """
     if jax.default_backend() == "tpu":
         from repro.kernels.kvq_attn.ops import kvq_decode_attn
-        return kvq_decode_attn(q, k_q, v_q, s_k, s_v, lengths)
+        return kvq_decode_attn(q, k_q, v_q, s_k, s_v, lengths, mesh=mesh)
     return decode_attention_intcache(q, k_q, v_q, s_k, s_v, lengths)
 
 
 def _decode_attn_paged(q, k_pool, v_pool, s_k, s_v, block_tbl,
-                       lengths) -> jnp.ndarray:
+                       lengths, mesh=None) -> jnp.ndarray:
     """Decode attention through a block table over the global cache pool.
 
     On TPU the Pallas paged kernel walks the slot's blocks directly (the
@@ -53,7 +53,7 @@ def _decode_attn_paged(q, k_pool, v_pool, s_k, s_v, block_tbl,
     if jax.default_backend() == "tpu":
         from repro.kernels.kvq_attn.ops import kvq_paged_decode_attn
         return kvq_paged_decode_attn(q, k_pool, v_pool, s_k, s_v,
-                                     block_tbl, lengths)
+                                     block_tbl, lengths, mesh=mesh)
     from repro.kernels.kvq_attn.ref import gather_paged_kv
     return decode_attention_intcache(
         q, gather_paged_kv(k_pool, block_tbl),
@@ -63,7 +63,7 @@ def _decode_attn_paged(q, k_pool, v_pool, s_k, s_v, block_tbl,
 
 
 def _spec_verify_attn(q, k_pool, v_pool, s_k, s_v, block_tbl,
-                      lengths) -> jnp.ndarray:
+                      lengths, mesh=None) -> jnp.ndarray:
     """Multi-query decode attention for the speculative verify-wave.
 
     q (n, C, H, D): C window queries per slot whose quantized K/V are
@@ -77,7 +77,8 @@ def _spec_verify_attn(q, k_pool, v_pool, s_k, s_v, block_tbl,
     from repro.kernels.kvq_attn.ops import kvq_spec_verify_attn
     return kvq_spec_verify_attn(q, k_pool, v_pool, s_k, s_v, block_tbl,
                                 lengths,
-                                use_pallas=jax.default_backend() == "tpu")
+                                use_pallas=jax.default_backend() == "tpu",
+                                mesh=mesh)
 
 
 # ==========================================================================
@@ -453,7 +454,7 @@ def attn_decode(cfg: ModelConfig, ctx: QuantCtx, p: Dict, x1: jnp.ndarray,
         q = quantize_act(ctx, q, p, "s_q")
         out = _decode_attn(
             q[:, 0], cache["k_q"], cache["v_q"], cache["s_k"], cache["s_v"],
-            cache["length"])
+            cache["length"], mesh=ctx.mesh)
         y = qlinear(ctx, out.reshape(B, 1, cfg.q_dim)[:, 0], p["wo"])
         return y[:, None], cache
     rope = None
@@ -485,7 +486,7 @@ def attn_decode(cfg: ModelConfig, ctx: QuantCtx, p: Dict, x1: jnp.ndarray,
         new["length"] = pos + 1
         out = _decode_attn_paged(q[:, 0], new["k_q"], new["v_q"],
                                  new["s_k"], new["s_v"], block_tbl,
-                                 new["length"])
+                                 new["length"], mesh=ctx.mesh)
         y = qlinear(ctx, out.reshape(B, cfg.q_dim), p["wo"])
         return y[:, None], new
     Sc = cache["k_q"].shape[2]
@@ -499,7 +500,7 @@ def attn_decode(cfg: ModelConfig, ctx: QuantCtx, p: Dict, x1: jnp.ndarray,
     new["length"] = cache["length"] + 1
     out = _decode_attn(
         q[:, 0], new["k_q"], new["v_q"], new["s_k"], new["s_v"],
-        jnp.minimum(new["length"], Sc))
+        jnp.minimum(new["length"], Sc), mesh=ctx.mesh)
     y = qlinear(ctx, out.reshape(B, cfg.q_dim), p["wo"])
     return y[:, None], new
 
@@ -548,8 +549,10 @@ def attn_chunk_prefill(cfg: ModelConfig, ctx: QuantCtx, p: Dict,
     # dequantized history, head-major (n, Hkv, Lh, D) -> seq-major; on TPU
     # a fused Pallas gather-dequant walks each row's table (no int8
     # intermediate in HBM), elsewhere the two-gather XLA reference
-    kh = gather_dequant_paged_kv(cache["k_q"], cache["s_k"], tbl)
-    vh = gather_dequant_paged_kv(cache["v_q"], cache["s_v"], tbl)
+    kh = gather_dequant_paged_kv(cache["k_q"], cache["s_k"], tbl,
+                                 mesh=ctx.mesh)
+    vh = gather_dequant_paged_kv(cache["v_q"], cache["s_v"], tbl,
+                                 mesh=ctx.mesh)
     kh = jnp.swapaxes(kh, 1, 2)
     vh = jnp.swapaxes(vh, 1, 2)
     kall = jnp.concatenate([kh, k.astype(jnp.float32)], axis=1)
@@ -623,6 +626,6 @@ def attn_spec_verify(cfg: ModelConfig, ctx: QuantCtx, p: Dict,
     # per-query valid extent: history + the window prefix through itself
     lens = offset[:, None] + 1 + jnp.arange(C)[None]
     out = _spec_verify_attn(q, new["k_q"], new["v_q"], new["s_k"],
-                            new["s_v"], tbl, lens)
+                            new["s_v"], tbl, lens, mesh=ctx.mesh)
     y = qlinear(ctx, out.reshape(B, C, cfg.q_dim).astype(x.dtype), p["wo"])
     return y, new

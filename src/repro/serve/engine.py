@@ -270,7 +270,7 @@ class ServeEngine:
                              and cfg.n_kv_heads % self.tp == 0) else ""
         self.ctx = make_ctx(policy, weights_layout=weights_layout,
                             w4a8_backend=w4a8_backend,
-                            attn_shard_mode=attn_mode)
+                            attn_shard_mode=attn_mode, mesh=mesh)
         if weights_layout == "w4a8" and not w4a8_use_pallas(self.ctx):
             # XLA:CPU can't fuse the nibble unpack into its gemm the way the
             # Pallas kernel does in-registers; cache the unpacked int8 plane
@@ -359,7 +359,7 @@ class ServeEngine:
             self.draft_ctx = make_ctx(self.spec.draft_policy or policy,
                                       weights_layout=weights_layout,
                                       w4a8_backend=w4a8_backend,
-                                      attn_shard_mode=attn_mode)
+                                      attn_shard_mode=attn_mode, mesh=mesh)
             # the draft over-commits up to k positions past the accepted
             # extent before rollback; its dense ring must never wrap
             # into live history
@@ -486,7 +486,7 @@ class ServeEngine:
         onto ``dst`` (sentinel dsts drop), everything else passes through."""
         def cp(path, leaf):
             if getattr(path[-1], "key", None) in _POOL_KEYS:
-                return copy_pool_blocks(leaf, src, dst)
+                return copy_pool_blocks(leaf, src, dst, mesh=self.mesh)
             return leaf
         return jax.tree_util.tree_map_with_path(cp, cache)
 

@@ -68,7 +68,8 @@ class TestHLOAnalysis:
         code = """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(model_parallel=8)
         w1 = jax.device_put(jnp.ones((5, 16, 64)),
                             NamedSharding(mesh, P(None, None, "model")))
         w2 = jax.device_put(jnp.ones((5, 64, 16)),
@@ -148,11 +149,10 @@ class TestCompression:
         feedback drives the *accumulated* bias to zero over steps."""
         code = """
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.runtime.compression import compressed_psum, \\
             init_error_feedback
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         g_global = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         true_mean = jnp.mean(g_global, 0)
 
@@ -160,7 +160,7 @@ class TestCompression:
             gs, e2 = compressed_psum({"w": g}, e, "data")
             return gs["w"], e2
 
-        f = shard_map(step, mesh=mesh,
+        f = jax.shard_map(step, mesh=mesh,
                       in_specs=(P("data"), {"w": P("data")}),
                       out_specs=(P("data"), {"w": P("data")}))
         err = init_error_feedback({"w": g_global})

@@ -7,7 +7,9 @@ Two tiers in one file:
   non-divisible fallback-to-replication path, the serve pool spec that
   must never shard the global block-id axis), the HLO collective-count /
   pool-all-gather helpers on synthetic modules, and the mesh-factory /
-  engine-knob validation errors.
+  engine-knob validation errors; plus tp=1 vs tp=4 greedy parity in a
+  subprocess with 4 forced host devices, on the XLA path and on the TPU's
+  kernel routing (interpret-mode Pallas).
 * **Mesh-backed (CI `mesh` job)** — skipped unless the session was
   launched with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
   Bit-exact token-stream parity between a tp=1 engine and tp=2 / tp=4
@@ -356,6 +358,76 @@ class TestShardedWaveHLO:
         spec = tuple(kq.sharding.spec) + (None,) * 5
         assert spec[2] == "model", kq.sharding
         assert all(ax is None for ax in tuple(eng.state["out"].sharding.spec))
+
+
+TP4_PARITY = """
+import sys
+import jax
+import numpy as np
+from repro.configs import get_reduced_config
+from repro.core.precision import parse_policy
+from repro.core.qat import calibrate_weight_scales
+from repro.launch.mesh import make_local_mesh
+from repro.models import init_params
+from repro.serve.engine import Request, ServeEngine
+from repro.serve.spec import SpecConfig
+
+kv_heads, kernels = int(sys.argv[1]), sys.argv[2] == "kernels"
+if kernels:
+    # route the serve path to its Pallas kernels as a TPU does; they stay in
+    # interpret mode, fixed when their modules are imported (here, first)
+    import repro.kernels.kvq_attn.ops, repro.kernels.w4a8.ops
+    jax.default_backend = lambda: "tpu"
+cfg = get_reduced_config("qwen2.5-3b").replace(n_kv_heads=kv_heads)
+params = calibrate_weight_scales(init_params(cfg, jax.random.PRNGKey(0)),
+                                 parse_policy("A8d-C8-W4"))
+
+
+def streams(mesh):
+    eng = ServeEngine(cfg, params, policy="A8d-C8-W4", slots=4,
+                      cache_len=128, max_new_cap=16, decode_block=4,
+                      prefill_bucket=16, kv_layout="paged", block_size=16,
+                      weights_layout="w4a8",
+                      spec=SpecConfig(k=3, draft_layers=1), mesh=mesh)
+    r = np.random.default_rng(7)
+    shared = r.integers(1, cfg.vocab_size, 24)
+    reqs = [Request(uid=i, prompt=np.concatenate(
+                [shared, r.integers(1, cfg.vocab_size, 3 + 5 * i)]
+            ).astype(np.int32), max_new_tokens=12, eos_id=-1)
+            for i in range(6)]
+    for rq in reqs:
+        eng.submit(rq)
+    st = eng.run_until_drained()
+    return [tuple(rq.generated) for rq in reqs], st
+
+
+base, _ = streams(None)
+got, st = streams(make_local_mesh(model_parallel=4))
+assert st["tp_degree"] == 4, st["tp_degree"]
+assert st["spec_waves"] > 0 and st["prefix_hit_tokens"] > 0, st
+assert got == base, (got, base)
+print("TP4_PARITY_OK")
+"""
+
+
+@pytest.mark.parametrize("kv_heads,path", [(4, "xla"), (2, "kernels")])
+def test_tp4_greedy_parity_subprocess(kv_heads, path):
+    """tp=4 greedy streams equal tp=1 on 4 forced host devices, in a
+    subprocess so the suite's own session keeps one device. ``xla``: KV
+    heads split over the mesh (4 of them). ``kernels``: the TPU routing
+    (Pallas kernels per device under a shard_map) with qwen2.5-3b's two
+    KV heads, which tp=4 cannot split, so each device holds them whole."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", TP4_PARITY, str(kv_heads),
+                          path], env=env, capture_output=True, text=True,
+                         timeout=600)
+    assert out.returncode == 0 and "TP4_PARITY_OK" in out.stdout, \
+        out.stderr[-3000:]
 
 
 @needs_mesh
