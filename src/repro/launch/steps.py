@@ -47,7 +47,13 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
                     batch_axes: tuple = ()) -> Callable:
     """QAT train step, paper-faithful: teacher forward (unquantized, no
     grad), student forward with fake-quant, pure-KD loss (default), AdamW
-    with LSQ scale updates (50x LR on activation scales)."""
+    with LSQ scale updates (50x LR on activation scales).
+
+    Its phases are ``jax.named_scope``s (``kd_loss``, ``optimizer``;
+    ``forward`` adds ``embed``, ``layers`` and ``head``), which reach
+    every HLO instruction's ``op_name``, the backward pass's included, and
+    so name device time in a profiler trace. They change metadata only,
+    not the compiled program."""
     policy = parse_policy(tcfg.precision)
     ctx = make_ctx(policy, act_calib_method=tcfg.act_calib_method,
                    attn_shard_mode=attn_shard_mode, batch_axes=batch_axes)
@@ -62,26 +68,29 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
 
         def loss_fn(p):
             logits, aux = forward(cfg, p, ctx, batch, remat=remat)
-            loss = silq_loss(_text_logits(cfg, logits), t_logits,
-                             batch["labels"], kd_ratio=tcfg.kd_ratio,
-                             kd_temperature=tcfg.kd_temperature,
-                             mask=batch.get("loss_mask"))
-            if cfg.is_moe:
-                loss = loss + MOE_AUX_COEF * aux["moe_aux"]
+            with jax.named_scope("kd_loss"):
+                loss = silq_loss(_text_logits(cfg, logits), t_logits,
+                                 batch["labels"], kd_ratio=tcfg.kd_ratio,
+                                 kd_temperature=tcfg.kd_temperature,
+                                 mask=batch.get("loss_mask"))
+                if cfg.is_moe:
+                    loss = loss + MOE_AUX_COEF * aux["moe_aux"]
             return loss
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        if tcfg.grad_clip:
-            from repro.optim.adamw import clip_by_global_norm
-            grads, _ = clip_by_global_norm(grads, tcfg.grad_clip)
-        lr = cosine_schedule(step, base_lr=base_lr,
-                             total_steps=tcfg.total_steps,
-                             warmup_steps=tcfg.warmup_steps,
-                             min_lr_ratio=tcfg.min_lr_ratio)
-        new_params, new_opt = adamw_update(
-            params, grads, opt_state, lr=lr, beta1=tcfg.beta1,
-            beta2=tcfg.beta2, eps=tcfg.eps, weight_decay=tcfg.weight_decay,
-            act_scale_lr_mult=tcfg.act_scale_lr_mult)
+        with jax.named_scope("optimizer"):
+            if tcfg.grad_clip:
+                from repro.optim.adamw import clip_by_global_norm
+                grads, _ = clip_by_global_norm(grads, tcfg.grad_clip)
+            lr = cosine_schedule(step, base_lr=base_lr,
+                                 total_steps=tcfg.total_steps,
+                                 warmup_steps=tcfg.warmup_steps,
+                                 min_lr_ratio=tcfg.min_lr_ratio)
+            new_params, new_opt = adamw_update(
+                params, grads, opt_state, lr=lr, beta1=tcfg.beta1,
+                beta2=tcfg.beta2, eps=tcfg.eps,
+                weight_decay=tcfg.weight_decay,
+                act_scale_lr_mult=tcfg.act_scale_lr_mult)
         return new_params, new_opt, {"loss": loss, "lr": lr}
 
     return train_step

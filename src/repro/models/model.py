@@ -287,18 +287,22 @@ def head_logits(cfg: ModelConfig, params, ctx: QuantCtx, x: jnp.ndarray,
 
 def forward(cfg: ModelConfig, params: Dict, ctx: QuantCtx, batch: Dict,
             collect_stats: bool = False, remat: bool = False):
-    """Training/teacher forward. Returns (logits, {"moe_aux", "qstats"})."""
-    x = _embed(cfg, params, batch)
+    """Training/teacher forward. Returns (logits, {"moe_aux", "qstats"}).
+    Its phases are named scopes: ``embed``, ``layers`` and ``head``."""
+    with jax.named_scope("embed"):
+        x = _embed(cfg, params, batch)
     S = x.shape[1]
     col: Optional[Dict] = {} if collect_stats else None
     consts = {"rope": _rope_for(cfg, batch, S), "enc_out": None}
     if cfg.is_encdec:
         consts["enc_out"] = _encode(cfg, ctx, params, batch, col)
-    x, cols, auxs, _ = _run_stack(cfg, ctx, params["segments"],
-                                  segment_plan(cfg), x, consts,
-                                  collect=collect_stats, remat=remat)
-    x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
-    logits = head_logits(cfg, params, ctx, x, col)
+    with jax.named_scope("layers"):
+        x, cols, auxs, _ = _run_stack(cfg, ctx, params["segments"],
+                                      segment_plan(cfg), x, consts,
+                                      collect=collect_stats, remat=remat)
+    with jax.named_scope("head"):
+        x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
+        logits = head_logits(cfg, params, ctx, x, col)
     aux = {"moe_aux": sum(auxs) if auxs else jnp.float32(0.0)}
     if collect_stats:
         col["segments"] = cols

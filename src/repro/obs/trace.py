@@ -10,6 +10,11 @@ carry the engine step index, events additionally the request uid, so a
 trace correlates "what the engine was doing" with "where each request's
 latency went".
 
+An enabled tracer puts each span on the profiler's clock too: the span
+also opens a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so
+a profiler trace shows the program's host phases beside the device's
+operations.
+
 Design constraints, in order:
 
 1. **Disabled means free.** The engine's TTFT/rate bookkeeping reads
@@ -17,14 +22,17 @@ Design constraints, in order:
    ``perf_counter`` calls — exactly the ``t0``/``dt`` plumbing it
    replaced); but with ``enabled=False`` nothing is recorded: ``event``
    / ``annotate`` return on one predicate, ``Span.__exit__`` commits
-   nothing, and the nesting stack is never touched. The
+   nothing, no profiler annotation is opened, and the nesting stack is
+   never touched. The
    ``observability`` benchmark section CI-gates this at < 2% tok/s.
 2. **Bounded memory.** The buffer is a ``deque(maxlen=capacity)``:
    long-running servers evict the oldest records instead of growing;
    ``dropped`` counts evictions so exports can say the window is
    truncated.
-3. **No dependencies.** Pure stdlib — importable from the scheduler /
-   allocator layers without touching jax.
+3. **No dependencies until used.** Pure stdlib at import — importable
+   from the scheduler / allocator layers without touching jax. jax is
+   imported only when an enabled tracer first opens a span (for its
+   ``TraceAnnotation``).
 
 Record shapes (plain dicts, the export layer's input contract)::
 
@@ -52,6 +60,19 @@ SPAN_NAMES = ("step", "admit", "schedule", "prefill_wave", "tail_wave",
 
 DEFAULT_CAPACITY = 1 << 16
 
+# profiler annotations of enabled spans are named ANNOTATION_PREFIX + name
+ANNOTATION_PREFIX = "repro."
+
+_annotation_cls = None                  # jax.profiler.TraceAnnotation
+
+
+def _annotation(name: str):
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(ANNOTATION_PREFIX + name)
+
 
 class Span:
     """One timed host-side phase. Use as a context manager::
@@ -64,7 +85,7 @@ class Span:
     (e.g. row counts known only after the work ran).
     """
 
-    __slots__ = ("_tracer", "name", "args", "t0", "dt")
+    __slots__ = ("_tracer", "name", "args", "t0", "dt", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[Dict]):
         self._tracer = tracer
@@ -72,11 +93,14 @@ class Span:
         self.args = args if args is not None else {}
         self.t0 = 0.0
         self.dt = 0.0
+        self._ann = None
 
     def __enter__(self) -> "Span":
         tr = self._tracer
         if tr.enabled:
             tr._stack.append(self)
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -87,6 +111,9 @@ class Span:
             if tr._stack and tr._stack[-1] is self:
                 tr._stack.pop()
             tr._commit(self)
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
 
 
 class Tracer:

@@ -323,3 +323,110 @@ class TestHTTPMetrics:
         # /v1/stats carries the matching histogram digest
         assert stats["metrics"]["ttft"]["count"] == 4
         assert json.loads(json.dumps(stats)) == stats
+
+
+class TestProfilerClock:
+    """The tracer on the profiler's clock: an enabled span is also a
+    ``repro.<name>`` profiler annotation, a disabled one is not."""
+
+    def test_disabled_tracer_opens_no_annotation_and_imports_no_jax(self):
+        import os
+        import subprocess
+        import sys
+        code = ("import sys\n"
+                "import repro.obs.trace as t\n"
+                "tr = t.Tracer(enabled=False)\n"
+                "with tr.span('decode_chunk'):\n"
+                "    pass\n"
+                "with t.NULL_TRACER.span('harvest'):\n"
+                "    pass\n"
+                "assert 'jax' not in sys.modules\n"
+                "assert t._annotation_cls is None and len(tr) == 0\n")
+        env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+        subprocess.run([sys.executable, "-c", code], check=True, env=env)
+
+    def test_disabled_span_never_builds_an_annotation(self, monkeypatch):
+        import repro.obs.trace as trace_mod
+
+        def boom(name):
+            raise AssertionError(f"annotation {name} opened")
+
+        monkeypatch.setattr(trace_mod, "_annotation", boom)
+        with Tracer(enabled=False).span("decode_chunk"):
+            pass
+
+    def test_enabled_span_is_a_profiler_annotation_on_the_device_clock(
+            self, tmp_path):
+        """``repro.<name>`` lands on the profiler's host plane, and the
+        operations run inside the span fall inside it."""
+        import jax
+        import jax.numpy as jnp
+        f = jax.jit(lambda x: jnp.tanh(x @ x) * 2.0)
+        x = jnp.ones((64, 64))
+        f(x).block_until_ready()
+        tr = Tracer()
+        jax.profiler.start_trace(str(tmp_path))
+        with tr.span("decode_chunk"):
+            f(x).block_until_ready()
+        jax.profiler.stop_trace()
+        spans, ops = [], []
+        for e in _profiled_events(tmp_path):
+            if e.name == "repro.decode_chunk":
+                spans.append((e.start_ns, e.end_ns))
+            elif dict(e.stats).get("hlo_module") == "jit__lambda":
+                ops.append((e.start_ns, e.end_ns))
+        assert len(spans) == 1 and ops
+        (s, e), = spans
+        assert all(s <= a and b <= e for a, b in ops)
+        assert [r["name"] for r in tr.events()] == ["decode_chunk"]
+
+    def test_nested_annotations_close_when_the_span_body_raises(
+            self, tmp_path):
+        import jax
+        tr = Tracer()
+        jax.profiler.start_trace(str(tmp_path))
+        with tr.span("step"):
+            with pytest.raises(RuntimeError):
+                with tr.span("sync"):
+                    raise RuntimeError("device lost")
+        with tr.span("harvest"):
+            pass
+        jax.profiler.stop_trace()
+        got = {e.name: (e.start_ns, e.end_ns) for e in
+               _profiled_events(tmp_path) if e.name.startswith("repro.")}
+        assert set(got) == {"repro.step", "repro.sync", "repro.harvest"}
+        (s0, e0), (s1, e1) = got["repro.step"], got["repro.sync"]
+        assert s0 <= s1 and e1 <= e0
+        assert got["repro.harvest"][0] >= e0
+        assert [(r["name"], r["depth"]) for r in tr.events()] == [
+            ("sync", 1), ("step", 0), ("harvest", 0)]
+
+    def test_every_engine_span_reaches_the_profiler_trace(
+            self, served, tmp_path):
+        """The serve engine's spans need nothing of their own to be on
+        the profiler's clock: each recorded span is one ``repro.*``
+        annotation."""
+        import jax
+        cfg, params = served
+        tr = Tracer()
+        eng = ServeEngine(cfg, params, slots=2, cache_len=32,
+                          decode_block=4, trace=tr)
+        eng.submit(_req(0, 6, max_new=6))
+        jax.profiler.start_trace(str(tmp_path))
+        eng.run_until_drained()
+        jax.profiler.stop_trace()
+        spans = [r["name"] for r in tr.events() if r["ph"] == "span"]
+        assert {"step", "decode_chunk", "sync"} <= set(spans)
+        annotations = [e.name[len("repro."):] for e in
+                       _profiled_events(tmp_path)
+                       if e.name.startswith("repro.")]
+        assert sorted(annotations) == sorted(spans)
+
+
+def _profiled_events(trace_dir):
+    """Every event of the one profiler trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = trace_dir.glob("**/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return [e for plane in pd.planes for line in plane.lines
+            for e in line.events]
