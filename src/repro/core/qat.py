@@ -146,11 +146,15 @@ def quantize_weight_p(ctx: QuantCtx, p: Dict[str, Any],
 def qlinear(ctx: QuantCtx, x: jnp.ndarray, p: Dict[str, Any],
             col: Optional[Dict[str, Any]] = None,
             act_bits: Optional[int] = None,
-            weight_bits: Optional[int] = None) -> jnp.ndarray:
+            weight_bits: Optional[int] = None,
+            out_major: bool = False) -> jnp.ndarray:
     """Quantized linear: fake-quant input + weight, then matmul (+ bias).
 
     ``act_bits``/``weight_bits`` override the body policy for special sites
-    (head: 8/8; router: 8/8).
+    (head: 8/8; router: 8/8). ``out_major`` says ``p["w"]`` is stored
+    (d_out, d_in) with ``s_w`` shaped (d_out, 1), as the tied head's
+    embedding table is: it is quantized and contracted as stored, so
+    neither it nor its gradient is transposed.
 
     Under ``weights_layout="w4a8"`` the matmul instead consumes the packed
     int4 export attached next to this linear (see ``attach_w4a8_exports``)
@@ -168,7 +172,8 @@ def qlinear(ctx: QuantCtx, x: jnp.ndarray, p: Dict[str, Any],
         return w4a8_qlinear(ctx, x, exp)
     xq = quantize_act(ctx, x, p, "s_in", col, bits=act_bits)
     wq = quantize_weight_p(ctx, p, bits=weight_bits)
-    y = jnp.einsum("...i,io->...o", xq, wq)
+    y = jnp.einsum("...i,oi->...o" if out_major else "...i,io->...o",
+                   xq, wq)
     if "b" in p:
         y = y + p["b"].astype(y.dtype)
     return y

@@ -9,6 +9,7 @@ from typing import Any, Callable, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.distill import silq_loss
@@ -26,6 +27,11 @@ def _text_logits(cfg: ModelConfig, logits: jnp.ndarray) -> jnp.ndarray:
     if cfg.family == "vlm" and cfg.vision_tokens:
         return logits[:, cfg.vision_tokens:]
     return logits
+
+
+def _row_major(x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` in the row-major layout the step's arguments come in."""
+    return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
 
 
 def attn_shard_mode_for(cfg: ModelConfig, model_axis: int) -> str:
@@ -78,6 +84,15 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
             return loss
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
+        if cfg.tie_embeddings:
+            # Left free, TPU layout assignment lays the tied table's
+            # gradient (head matmul plus embedding scatter-add)
+            # column-major, runs its AdamW update that way, and so re-lays
+            # the table and both its f32 moments into and out of every
+            # step. Other leaves are left free: pinned, a sharded step
+            # gains copies of its sharded weights and moments.
+            embed = dict(grads["embed"], w=_row_major(grads["embed"]["w"]))
+            grads = dict(grads, embed=embed)
         with jax.named_scope("optimizer"):
             if tcfg.grad_clip:
                 from repro.optim.adamw import clip_by_global_norm

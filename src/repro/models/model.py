@@ -273,8 +273,11 @@ def _encode(cfg, ctx, params, batch, col):
 def head_logits(cfg: ModelConfig, params, ctx: QuantCtx, x: jnp.ndarray,
                 col: Optional[Dict] = None) -> jnp.ndarray:
     hb = ctx.policy.head_bits
-    if cfg.tie_embeddings:
-        p = {"w": params["embed"]["w"].T, "s_w": params["head"]["s_w"],
+    tied = cfg.tie_embeddings
+    if tied:
+        # the (V, d) table as stored; its per-row s_w (1, V) viewed as (V, 1)
+        p = {"w": params["embed"]["w"],
+             "s_w": params["head"]["s_w"].reshape(-1, 1),
              "s_in": params["head"]["s_in"]}
         if "w4a8" in params["head"]:
             # packed export of embed.w.T (attach_w4a8_exports tied-head case)
@@ -282,7 +285,7 @@ def head_logits(cfg: ModelConfig, params, ctx: QuantCtx, x: jnp.ndarray,
     else:
         p = params["head"]
     return qlinear(ctx, x, p, subcol(col, "head"),
-                   act_bits=hb, weight_bits=hb)
+                   act_bits=hb, weight_bits=hb, out_major=tied)
 
 
 def forward(cfg: ModelConfig, params: Dict, ctx: QuantCtx, batch: Dict,

@@ -5,13 +5,16 @@ import numpy as np
 import pytest
 
 from repro.configs import get_reduced_config
+from repro.configs.base import TrainConfig
 from repro.core.distill import kd_loss, next_token_loss, silq_loss
 from repro.core.precision import PAPER_POLICIES, parse_policy
 from repro.core.qat import (ACT_SCALE_KEYS, act_scale_mask,
                             calibrate_weight_scales, export_linear_int,
                             init_linear, make_ctx, merge_act_scales, qlinear,
                             scale_mask)
-from repro.models import forward, init_params
+from repro.launch.steps import make_train_step
+from repro.models import forward, head_logits, init_params
+from repro.optim import adamw_init
 
 
 class TestLosses:
@@ -158,3 +161,88 @@ class TestQATState:
             y = qlinear(ctx, x, p2, weight_bits=bits, act_bits=16)
             errs.append(float(jnp.mean((y - y_ref) ** 2)))
         assert errs[0] > errs[1] > errs[2]
+
+
+class TestTiedHead:
+    """The tied head fake-quantizes and contracts the embedding table as
+    stored, (V, d): the same numbers as the linear on ``embed.w.T`` it
+    replaces, and a train step that never transposes the table."""
+
+    @pytest.mark.parametrize("policy,mode", [
+        ("A8d-C8-W4", "train"),       # the student
+        ("A8s-C8-W4", "train"),       # static input scale: LSQ on s_in
+        ("A16-C16-W16", "off"),       # the teacher
+        ("A8s-C8-W4", "calib"),       # fills col["head"]
+    ])
+    def test_matches_linear_on_transposed_table(self, rng, policy, mode):
+        cfg = get_reduced_config("qwen2.5-3b")
+        assert cfg.tie_embeddings and cfg.vocab_size != cfg.d_model
+        params = calibrate_weight_scales(init_params(cfg, rng),
+                                         parse_policy("A8d-C8-W4"))
+        ctx = make_ctx(policy, mode=mode)
+        hb = ctx.policy.head_bits
+        kx, kg = jax.random.split(jax.random.fold_in(rng, 7))
+        x = (2.0 * jax.random.normal(kx, (2, 16, cfg.d_model))
+             ).astype(jnp.bfloat16)
+        w, s_w = params["embed"]["w"], params["head"]["s_w"]
+        s_in = jnp.float32(0.05)          # clips part of x
+        cols = {}
+
+        def tied(w, s_w, s_in, x):
+            p = dict(params, embed={"w": w},
+                     head={"s_w": s_w, "s_in": s_in})
+            col = {}
+            y = head_logits(cfg, p, ctx, x, col)
+            cols["tied"] = col["head"]
+            return y
+
+        def linear(w, s_w, s_in, x):
+            cols["linear"] = {}
+            return qlinear(ctx, x, {"w": w.T, "s_w": s_w, "s_in": s_in},
+                           cols["linear"], act_bits=hb, weight_bits=hb)
+
+        args = (w, s_w, s_in, x)
+        y_t, vjp_t = jax.vjp(tied, *args)
+        y_l, vjp_l = jax.vjp(linear, *args)
+        assert y_t.shape == (2, 16, cfg.vocab_size)
+        np.testing.assert_array_equal(np.asarray(y_t), np.asarray(y_l))
+        g = jax.random.normal(kg, y_t.shape).astype(y_t.dtype)
+        for name, a, b in zip(("embed.w", "head.s_w", "head.s_in", "x"),
+                              vjp_t(g), vjp_l(g)):
+            assert a.shape == b.shape, name
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+        if mode == "calib":
+            tied(*args), linear(*args)     # the collectors, outside the vjp
+            assert set(cols["tied"]) == {"s_in"}
+            np.testing.assert_array_equal(np.asarray(cols["tied"]["s_in"]),
+                                          np.asarray(cols["linear"]["s_in"]))
+
+    @pytest.mark.parametrize("arch", ["qwen2.5-3b", "qwen2-7b"])
+    def test_train_step_never_transposes_the_table(self, rng, arch):
+        cfg = get_reduced_config(arch)
+        V, d = cfg.vocab_size, cfg.d_model
+        params = jax.eval_shape(lambda k: init_params(cfg, k), rng)
+        opt = jax.eval_shape(adamw_init, params)
+        batch = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32)
+                 for k in ("tokens", "labels")}
+        step = make_train_step(cfg, TrainConfig(total_steps=10, ref_steps=10,
+                                                batch_size=2, seq_len=16))
+        args = (params, params, opt, batch,
+                jax.ShapeDtypeStruct((), jnp.int32))
+        text = jax.jit(step).lower(*args).as_text()
+        table = (f"tensor<{V}x{d}x", f"tensor<{d}x{V}x")
+        transposes = [ln for ln in text.splitlines()
+                      if "stablehlo.transpose" in ln
+                      and any(t in ln for t in table)]
+        if cfg.tie_embeddings:
+            assert "w" not in params["head"]
+            assert params["head"]["s_w"].shape == (1, V)
+            assert transposes == [], transposes
+        else:
+            assert params["head"]["w"].shape == (d, V)
+        # the step hands back the tree it was given, leaf for leaf
+        new_params, _, _ = jax.eval_shape(step, *args)
+        assert jax.tree.structure(new_params) == jax.tree.structure(params)
+        for a, b in zip(jax.tree.leaves(new_params), jax.tree.leaves(params)):
+            assert (a.shape, a.dtype) == (b.shape, b.dtype)

@@ -1,17 +1,22 @@
-"""Serve-path Pallas kernels compiled for a described TPU v5e.
+"""Serve-path Pallas kernels, and the QAT step's layouts, compiled for a
+described TPU v5e.
 
 Interpret-mode tests check what the kernels compute; only the TPU compiler
 checks that Mosaic accepts their block layouts. Each case lowers a kernel
 wrapper of ``ops.py`` at qwen2.5-3b widths with interpret mode off and
 compiles it for one chip of a ``v5e:2x2`` topology that is described, not
 attached (about a second each). Nothing runs, so results are not checked
-here: the interpret-mode parity tests and ``chip_smoke.py`` do that.
+here: the interpret-mode parity tests and ``chip_smoke.py`` do that. Only
+the TPU's layout assignment shows whether the QAT step re-lays its tied
+embedding table, so that is checked here too.
 
 The topology is described inside a module fixture, never at import: only one
 process may load the TPU library, and xdist workers import every test file.
 """
+import contextlib
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,9 +25,14 @@ import pytest
 from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.configs import get_config
+from repro.configs import get_config, get_reduced_config
+from repro.configs.base import ShapeConfig, TrainConfig
 from repro.kernels.kvq_attn import ops as kvq
 from repro.kernels.w4a8 import ops as w4a8
+from repro.launch.specs import batch_struct, opt_struct, param_struct
+from repro.launch.steps import make_train_step
+from repro.runtime.sharding import (batch_shardings, opt_shardings,
+                                    param_shardings)
 
 CFG = get_config("qwen2.5-3b")
 D, H, HKV = CFG.resolved_head_dim, CFG.n_heads, CFG.n_kv_heads
@@ -165,3 +175,45 @@ def test_small_pool_blocks_refused_on_hardware(hardware):
     with pytest.raises(ValueError, match="block_size >= 32"):
         jax.eval_shape(kvq.kvq_paged_decode_attn, q, pool, pool, sc, sc,
                        jnp.zeros((1, 2), i32), jnp.ones((1,), i32))
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (2, 2)],
+                         ids=["one_chip", "data2_model2"])
+def test_train_step_keeps_tied_table_row_major(topo, one_chip, mesh_shape):
+    """Left to TPU layout assignment, the tied head's weight gradient comes
+    out column-major and the step copies the table and both its AdamW
+    moments to that layout and back. With the table's gradient in the
+    arguments' layout the step copies none of its arguments: on one chip,
+    and on a (data, model) mesh under the dry run's sharding rules, where
+    pinning every gradient would copy sharded weights and moments."""
+    V, d = 1024, 128
+    cfg = get_reduced_config("qwen2.5-3b").replace(
+        vocab_size=V, d_model=d, n_heads=4, n_kv_heads=2, head_dim=32,
+        d_ff=2 * d)
+    assert cfg.tie_embeddings
+    params = param_struct(cfg)
+    opt = opt_struct(params)
+    batch = batch_struct(cfg, ShapeConfig("t", "train", 128, 4),
+                         with_labels=True)
+    args = (params, params, opt, batch, jax.ShapeDtypeStruct((), i32))
+    if mesh_shape is None:
+        args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), args)
+        mesh, shardings = contextlib.nullcontext(), {}
+    else:
+        mesh = Mesh(np.array(topo.devices[:4]).reshape(mesh_shape),
+                    ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+        psh = param_shardings(cfg, mesh, params)
+        shardings = {"in_shardings": (
+            psh, psh, opt_shardings(psh, opt), batch_shardings(mesh, batch),
+            None)}
+    step = make_train_step(cfg, TrainConfig())
+    with mesh:
+        hlo = jax.jit(step, donate_argnums=(0, 2), **shardings).lower(
+            *args).compile().as_text()
+    relaid = re.findall(rf"= \w+\[(?:{V},{d}|{d},{V})\]\S* "
+                        r"(?:copy|transpose)\(", hlo)
+    assert relaid == []
+    args_copied = re.findall(
+        r"= \S+ copy\(.*op_name=\"(?:params|opt_state)[^\"]*", hlo)
+    assert args_copied == []
