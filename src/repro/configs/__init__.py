@@ -18,6 +18,7 @@ _ARCH_MODULES = {
     "recurrentgemma-2b": "recurrentgemma_2b",
     "qwen2-vl-2b": "qwen2_vl_2b",
     "xlstm-125m": "xlstm_125m",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)

@@ -17,9 +17,10 @@ BLOCK_LOCAL_ATTN = "local_attn"  # sliding-window causal attention
 BLOCK_RGLRU = "rglru"          # RecurrentGemma RG-LRU recurrent block
 BLOCK_MLSTM = "mlstm"          # xLSTM matrix-memory block
 BLOCK_SLSTM = "slstm"          # xLSTM scalar-memory block
+BLOCK_MAMBA2 = "mamba2"        # Mamba-2 (SSD) mixer + SwiGLU MLP
 
 ATTENTION_BLOCKS = (BLOCK_ATTN, BLOCK_LOCAL_ATTN)
-RECURRENT_BLOCKS = (BLOCK_RGLRU, BLOCK_MLSTM, BLOCK_SLSTM)
+RECURRENT_BLOCKS = (BLOCK_RGLRU, BLOCK_MLSTM, BLOCK_SLSTM, BLOCK_MAMBA2)
 
 
 @dataclass(frozen=True)
@@ -36,7 +37,7 @@ class ModelConfig:
     # attention details ------------------------------------------------------
     qkv_bias: bool = False
     qk_norm: bool = False
-    rope_theta: float = 10_000.0
+    rope_theta: float = 10_000.0    # 0 -> no rotary embedding (NoPE)
     sliding_window: int = 0         # 0 -> no SWA for BLOCK_ATTN layers
     local_window: int = 2048        # window for BLOCK_LOCAL_ATTN layers
     # block pattern ----------------------------------------------------------
@@ -56,6 +57,16 @@ class ModelConfig:
     conv1d_width: int = 4           # temporal conv width in RG-LRU block
     mlstm_proj_factor: float = 2.0
     slstm_proj_factor: float = 4.0 / 3.0
+    # Mamba-2 mixer (its conv is conv1d_width wide; B and C in one group)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_chunk: int = 256            # SSD chunk length
+    # Granite multipliers; the defaults emit no operation ----------------------
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0   # scales each residual branch
+    attention_multiplier: float = 0.0  # score scale; 0 -> 1/sqrt(head_dim)
+    logits_scaling: float = 1.0        # logits are divided by it
     # misc -----------------------------------------------------------------------
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
@@ -88,6 +99,10 @@ class ModelConfig:
         return tuple((pat * reps)[: self.n_layers])
 
     @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
 
@@ -99,7 +114,7 @@ class ModelConfig:
     def supports_long_context(self) -> bool:
         """True if decode memory is sub-linear in context (bounded cache)."""
         kinds = set(self.layer_kinds())
-        if kinds & {BLOCK_RGLRU, BLOCK_MLSTM, BLOCK_SLSTM}:
+        if kinds & set(RECURRENT_BLOCKS):
             return True
         # pure attention: only if *every* attention layer is window-bounded
         if BLOCK_ATTN in kinds and self.sliding_window == 0:
@@ -128,6 +143,12 @@ class ModelConfig:
         mlstm_blk = 2 * d * m_in + m_in * d + 3 * m_in * m_in + 2 * m_in
         s_in = int(self.slstm_proj_factor * d)
         slstm_blk = 8 * d * d + 2 * d * s_in  # w_x + r_h (4d each) + up/down
+        inner, N, Hs = self.ssm_inner, self.ssm_state, self.ssm_heads
+        conv_ch = inner + 2 * N                   # x, B, C
+        mamba_blk = (d * (inner + conv_ch + Hs)  # in_proj: z, xBC, dt
+                     + inner * d                  # out_proj
+                     + (self.conv1d_width + 1) * conv_ch  # conv + bias
+                     + 3 * Hs + inner)            # A_log, D, dt_bias, norm
         total = active = 0
         for kind in self.layer_kinds():
             if kind in ATTENTION_BLOCKS:
@@ -139,6 +160,8 @@ class ModelConfig:
                 t = a = mlstm_blk + (dense_mlp if self.d_ff else 0)
             elif kind == BLOCK_SLSTM:
                 t = a = slstm_blk + (dense_mlp if self.d_ff else 0)
+            elif kind == BLOCK_MAMBA2:
+                t = a = mamba_blk + dense_mlp
             else:
                 raise ValueError(kind)
             total += t

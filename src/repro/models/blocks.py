@@ -242,6 +242,10 @@ def _qkv(cfg: ModelConfig, ctx: QuantCtx, p: Dict, xq: jnp.ndarray,
         cos, sin = rope
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+    if cfg.attention_multiplier:
+        # the attention functions scale scores by 1/sqrt(hd): fold the
+        # config's score scale (Granite's 1/hd) into q
+        q = q * (cfg.attention_multiplier * hd ** 0.5)
     # paper sites: query INT16, cache C-bits
     q = quantize_act(ctx, q, p, "s_q", col)
     k = quantize_act(ctx, k, p, "s_k", col)

@@ -2,8 +2,9 @@
 
 One decoder skeleton parameterized by ``ModelConfig.block_pattern``:
 dense/MoE GQA transformers (qwen*, mixtral, moonshot), hybrid RG-LRU +
-local-attention (recurrentgemma), mLSTM/sLSTM (xlstm), an encoder-decoder
-wrapper (whisper), and an M-RoPE VLM backbone (qwen2-vl).
+local-attention (recurrentgemma), hybrid Mamba-2 + NoPE attention with
+Granite's multipliers (granite-4.0-h), mLSTM/sLSTM (xlstm), an
+encoder-decoder wrapper (whisper), and an M-RoPE VLM backbone (qwen2-vl).
 
 Layers are scanned: the repeating super-block (= block_pattern) is stacked
 along a leading ``repeat`` axis and driven by ``lax.scan``, keeping HLO size
@@ -23,8 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import (ATTENTION_BLOCKS, BLOCK_ATTN,
-                                BLOCK_LOCAL_ATTN, BLOCK_MLSTM, BLOCK_RGLRU,
-                                BLOCK_SLSTM, ModelConfig)
+                                BLOCK_LOCAL_ATTN, BLOCK_MAMBA2, BLOCK_MLSTM,
+                                BLOCK_RGLRU, BLOCK_SLSTM, ModelConfig)
 from repro.core.qat import QuantCtx, init_linear, qlinear
 from repro.models import blocks as B
 from repro.models import recurrent as R
@@ -67,6 +68,10 @@ def _init_block(cfg: ModelConfig, kind: str, key, *, decoder_cross: bool,
             else B.init_mlp(cfg, ks[2], dtype))
     elif kind == BLOCK_RGLRU:
         p["rglru"] = R.init_rglru(cfg, ks[0], dtype)
+        p["ln2"] = init_norm(cfg.d_model, cfg.norm_type, dtype)
+        p["mlp"] = B.init_mlp(cfg, ks[1], dtype)
+    elif kind == BLOCK_MAMBA2:
+        p["mamba"] = R.init_mamba2(cfg, ks[0], dtype)
         p["ln2"] = init_norm(cfg.d_model, cfg.norm_type, dtype)
         p["mlp"] = B.init_mlp(cfg, ks[1], dtype)
     elif kind == BLOCK_MLSTM:
@@ -138,6 +143,14 @@ def _rope_for(cfg: ModelConfig, batch: Dict, S: int):
     return rope_tables(jnp.arange(S), hd, cfg.rope_theta)
 
 
+def _residual(cfg: ModelConfig, x: jnp.ndarray, y: jnp.ndarray):
+    """x + y, the branch scaled by ``residual_multiplier`` where the
+    config sets one (Granite)."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
 def _ffn_tail(cfg, ctx, p, x, col=None):
     """ln2 + MoE/MLP + residual — the post-attention half of an attention
     block, shared by the forward/prefill, decode, and chunked-prefill
@@ -148,7 +161,7 @@ def _ffn_tail(cfg, ctx, p, x, col=None):
     else:
         y = B.mlp_fwd(cfg, ctx, p["mlp"], h, subcol(col, "mlp"))
         aux = jnp.float32(0.0)
-    return x + y, aux
+    return _residual(cfg, x, y), aux
 
 
 def _block_fwd(cfg, ctx, kind, p, x, consts, col, *, prefill=False):
@@ -169,7 +182,7 @@ def _block_fwd(cfg, ctx, kind, p, x, consts, col, *, prefill=False):
         else:
             a = B.attn_fwd(cfg, ctx, p["attn"], h, consts["rope"],
                            subcol(col, "attn"), window=window)
-        x = x + a
+        x = _residual(cfg, x, a)
         if "xattn" in p:
             h = norm(x, p["ln_x"], cfg.norm_type, cfg.norm_eps)
             if prefill:
@@ -181,7 +194,7 @@ def _block_fwd(cfg, ctx, kind, p, x, consts, col, *, prefill=False):
                 a = B.attn_fwd(cfg, ctx, p["xattn"], h, None,
                                subcol(col, "xattn"),
                                enc_out=consts["enc_out"])
-            x = x + a
+            x = _residual(cfg, x, a)
         x, aux = _ffn_tail(cfg, ctx, p, x, col)
     elif kind == BLOCK_RGLRU:
         h = norm(x, p["ln1"], cfg.norm_type, cfg.norm_eps)
@@ -190,9 +203,21 @@ def _block_fwd(cfg, ctx, kind, p, x, consts, col, *, prefill=False):
                                        subcol(col, "rglru"))
         else:
             y = R.rglru_fwd(cfg, ctx, p["rglru"], h, subcol(col, "rglru"))
-        x = x + y
+        x = _residual(cfg, x, y)
         h = norm(x, p["ln2"], cfg.norm_type, cfg.norm_eps)
-        x = x + B.mlp_fwd(cfg, ctx, p["mlp"], h, subcol(col, "mlp"))
+        x = _residual(cfg, x, B.mlp_fwd(cfg, ctx, p["mlp"], h,
+                                        subcol(col, "mlp")))
+    elif kind == BLOCK_MAMBA2:
+        h = norm(x, p["ln1"], cfg.norm_type, cfg.norm_eps)
+        with jax.named_scope("mamba"):
+            y = R.mamba2_fwd(cfg, ctx, p["mamba"], h, subcol(col, "mamba"),
+                             return_cache=prefill)
+        if prefill:
+            y, cache = y
+        x = _residual(cfg, x, y)
+        h = norm(x, p["ln2"], cfg.norm_type, cfg.norm_eps)
+        x = _residual(cfg, x, B.mlp_fwd(cfg, ctx, p["mlp"], h,
+                                        subcol(col, "mlp")))
     elif kind in (BLOCK_MLSTM, BLOCK_SLSTM):
         h = norm(x, p["ln1"], cfg.norm_type, cfg.norm_eps)
         mod = R.mlstm_prefill if kind == BLOCK_MLSTM else R.slstm_prefill
@@ -201,7 +226,7 @@ def _block_fwd(cfg, ctx, kind, p, x, consts, col, *, prefill=False):
             y, cache = mod(cfg, ctx, p["cell"], h, subcol(col, "cell"))
         else:
             y = fwd(cfg, ctx, p["cell"], h, subcol(col, "cell"))
-        x = x + y
+        x = _residual(cfg, x, y)
     else:
         raise ValueError(kind)
     return x, aux, cache
@@ -234,9 +259,17 @@ def _run_stack(cfg, ctx, segments_params, plan, x, consts, *,
     return x, cols, auxs, caches
 
 
-def _embed(cfg: ModelConfig, params, batch: Dict) -> jnp.ndarray:
-    tokens = batch["tokens"]
+def _embed_tokens(cfg: ModelConfig, params, tokens: jnp.ndarray):
+    """Token embeddings, times ``embedding_multiplier`` where the config
+    sets one (Granite)."""
     x = jnp.take(params["embed"]["w"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _embed(cfg: ModelConfig, params, batch: Dict) -> jnp.ndarray:
+    x = _embed_tokens(cfg, params, batch["tokens"])
     if "patches" in batch:          # VLM: precomputed patch-embedding prefix
         x = jnp.concatenate([batch["patches"].astype(x.dtype), x], axis=1)
     if "pos_embed" in params:
@@ -284,8 +317,11 @@ def head_logits(cfg: ModelConfig, params, ctx: QuantCtx, x: jnp.ndarray,
             p["w4a8"] = params["head"]["w4a8"]
     else:
         p = params["head"]
-    return qlinear(ctx, x, p, subcol(col, "head"),
-                   act_bits=hb, weight_bits=hb, out_major=tied)
+    logits = qlinear(ctx, x, p, subcol(col, "head"),
+                     act_bits=hb, weight_bits=hb, out_major=tied)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def forward(cfg: ModelConfig, params: Dict, ctx: QuantCtx, batch: Dict,
@@ -370,26 +406,30 @@ def _block_decode(cfg, ctx, kind, p, x1, cache, positions, block_tbl=None):
         a, new_sa = B.attn_decode(cfg, ctx, p["attn"], h, cache["self"],
                                   positions, window=window,
                                   block_tbl=block_tbl)
-        x1 = x1 + a
+        x1 = _residual(cfg, x1, a)
         new_cache = {"self": new_sa}
         if "xattn" in p:
             h = norm(x1, p["ln_x"], cfg.norm_type, cfg.norm_eps)
             a, _ = B.attn_decode(cfg, ctx, p["xattn"], h, cache["cross"],
                                  positions, cross=True)
-            x1 = x1 + a
+            x1 = _residual(cfg, x1, a)
             new_cache["cross"] = cache["cross"]
         x1, _ = _ffn_tail(cfg, ctx, p, x1)
         return x1, new_cache
-    if kind == BLOCK_RGLRU:
+    if kind in (BLOCK_RGLRU, BLOCK_MAMBA2):
         h = norm(x1, p["ln1"], cfg.norm_type, cfg.norm_eps)
-        y, new_c = R.rglru_decode(cfg, ctx, p["rglru"], h, cache)
-        x1 = x1 + y
+        if kind == BLOCK_RGLRU:
+            y, new_c = R.rglru_decode(cfg, ctx, p["rglru"], h, cache)
+        else:
+            with jax.named_scope("mamba"):
+                y, new_c = R.mamba2_decode(cfg, ctx, p["mamba"], h, cache)
+        x1 = _residual(cfg, x1, y)
         h = norm(x1, p["ln2"], cfg.norm_type, cfg.norm_eps)
-        return x1 + B.mlp_fwd(cfg, ctx, p["mlp"], h), new_c
+        return _residual(cfg, x1, B.mlp_fwd(cfg, ctx, p["mlp"], h)), new_c
     h = norm(x1, p["ln1"], cfg.norm_type, cfg.norm_eps)
     dec = R.mlstm_decode if kind == BLOCK_MLSTM else R.slstm_decode
     y, new_c = dec(cfg, ctx, p["cell"], h, cache)
-    return x1 + y, new_c
+    return _residual(cfg, x1, y), new_c
 
 
 def decode_step(cfg: ModelConfig, params: Dict, ctx: QuantCtx,
@@ -402,8 +442,7 @@ def decode_step(cfg: ModelConfig, params: Dict, ctx: QuantCtx,
     """
     positions = cache["position"]
     block_tbl = cache.get("block_tbl")
-    batch = {"tokens": tokens1, "pos_offset": 0}
-    x = jnp.take(params["embed"]["w"], tokens1, axis=0)
+    x = _embed_tokens(cfg, params, tokens1)
     if "pos_embed" in params:
         x = x + jnp.take(params["pos_embed"]["w"],
                          jnp.minimum(positions,
@@ -444,7 +483,7 @@ def _tail_prologue(cfg: ModelConfig, params: Dict, tokens: jnp.ndarray,
                          "(init_cache(..., num_blocks=...))")
     C = tokens.shape[1]
     positions = offset[:, None] + jnp.arange(C)[None]       # (n, C)
-    x = jnp.take(params["embed"]["w"], tokens, axis=0)      # (n, C, d)
+    x = _embed_tokens(cfg, params, tokens)                  # (n, C, d)
     if "pos_embed" in params:
         pe = params["pos_embed"]["w"]
         x = x + jnp.take(pe, jnp.minimum(positions, pe.shape[0] - 1),
@@ -482,7 +521,7 @@ def _tail_stack(cfg: ModelConfig, params: Dict, ctx: QuantCtx,
                 a, new_sa = attn_fn(cfg, ctx, p["attn"], h, rope,
                                     layer_c[str(i)]["self"], tbl, slot,
                                     offset, chunk_len)
-                xc = xc + a
+                xc = _residual(cfg, xc, a)
                 xc, _ = _ffn_tail(cfg, ctx, p, xc)
                 new_lc[str(i)] = {"self": new_sa}
             return xc, new_lc
@@ -631,6 +670,8 @@ def init_cache(cfg: ModelConfig, ctx: QuantCtx, batch_size: int,
             return R.init_rglru_cache(cfg, batch_size, dtype=qdt)
         if kind == BLOCK_MLSTM:
             return R.init_mlstm_cache(cfg, batch_size, dtype=qdt)
+        if kind == BLOCK_MAMBA2:
+            return R.init_mamba2_cache(cfg, batch_size, dtype=qdt)
         return R.init_slstm_cache(cfg, batch_size, dtype=qdt)
 
     segments = []

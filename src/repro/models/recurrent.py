@@ -1,6 +1,7 @@
 """Recurrent block family: RG-LRU (RecurrentGemma/Griffin), mLSTM and sLSTM
-(xLSTM). SiLQ sites: all projection/gate *linears* carry A-bit input + W4
-per-channel weight quantizers; the element-wise recurrences themselves run
+(xLSTM), and the Mamba-2 mixer (Granite 4.0-H). SiLQ sites: all
+projection/gate *linears* carry A-bit input + W4 per-channel weight
+quantizers; the element-wise recurrences themselves run
 fp32 (they are fp16 ops on NorthPole too — DESIGN.md §Arch-applicability).
 The stored recurrent state is the cache analogue and is quantized to C-bits
 on the serving path (``state_q`` + scale).
@@ -16,6 +17,9 @@ TPU adaptation notes:
   used).
 * sLSTM has a non-linearizable hidden->gate feedback -> lax.scan over time
   (small matvecs; it exists in 2/12 layers of the assigned config).
+* Mamba-2's SSD is mLSTM's chunked algebra (q = C, k = B, v = x dt,
+  log-decay A dt) with B and C shared by all heads; its state is
+  (heads, head_dim, d_state).
 """
 from __future__ import annotations
 
@@ -161,6 +165,21 @@ def rglru_decode(cfg: ModelConfig, ctx: QuantCtx, p: Dict, x1: jnp.ndarray,
 
 
 # ==========================================================================
+# Chunked linear recurrences (mLSTM, Mamba-2 SSD)
+# ==========================================================================
+
+def _causal_decay(cum: jnp.ndarray) -> jnp.ndarray:
+    """exp(cum_t - cum_s) for s <= t, else 0, from the inclusive cumsum
+    ``cum`` (..., L) of a chunk's log-decays: (..., L, L) over (t, s)."""
+    L = cum.shape[-1]
+    decay = cum[..., :, None] - cum[..., None, :]
+    tri = jnp.tril(jnp.ones((L, L), bool))
+    # mask BEFORE exp: upper-triangle decay is positive and can overflow,
+    # poisoning the where() gradient with inf * 0 = NaN
+    return jnp.exp(jnp.where(tri, decay, -jnp.inf))
+
+
+# ==========================================================================
 # mLSTM block (xLSTM matrix memory, chunked parallel form)
 # ==========================================================================
 
@@ -237,13 +256,8 @@ def mlstm_fwd(cfg: ModelConfig, ctx: QuantCtx, p: Dict, x: jnp.ndarray,
         qf = qi.astype(jnp.float32) * scale
         kf = ki.astype(jnp.float32)
         scores = jnp.einsum("bthd,bshd->bhts", qf, kf)
-        decay = cum[:, :, None] - cum[:, None, :, :]     # (B,t,s,H)
-        tri = jnp.tril(jnp.ones((L, L), bool))
-        # mask BEFORE exp: upper-triangle decay is positive and can overflow,
-        # poisoning the where() gradient with inf * 0 = NaN
-        decay = jnp.where(tri[None, :, :, None], decay, -jnp.inf)
-        dmask = jnp.exp(decay)
-        w_ts = scores * jnp.moveaxis(dmask, 3, 1) * \
+        dmask = _causal_decay(jnp.moveaxis(cum, 1, 2))    # (B,H,t,s)
+        w_ts = scores * dmask * \
             jnp.moveaxis(ii, 2, 1)[:, :, None, :].astype(jnp.float32)
         intra = jnp.einsum("bhts,bshe->bthe", w_ts, vi_n(vi))
         # inter-chunk: q_t exp(cum_t) @ state
@@ -395,3 +409,162 @@ def slstm_decode(cfg: ModelConfig, ctx: QuantCtx, p: Dict, x1: jnp.ndarray,
     y = qlinear(ctx, u, p["w_down"])
     hq, hs = cache_quantize(ctx, h.astype(jnp.bfloat16))
     return y, {"state_q": hq, "s_state": hs, "c": c.astype(jnp.float32)}
+
+
+# ==========================================================================
+# Mamba-2 mixer (SSD, chunked parallel form)
+# ==========================================================================
+
+def init_mamba2(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> Dict:
+    """in_proj d -> z | x,B,C | dt; depthwise conv over x,B,C; per-head
+    A_log, D, dt_bias (f32); gated RMSNorm gain; out_proj inner -> d.
+    A = -exp(A_log) with A in [1, 16]; dt = softplus(dt_bias) log-uniform
+    in [1e-3, 0.1] (the Mamba-2 initialisation)."""
+    d, inner, N, H = cfg.d_model, cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads
+    conv_ch = inner + 2 * N
+    ks = jax.random.split(key, 6)
+    dt = jnp.exp(jax.random.uniform(ks[3], (H,), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(0.1)))
+    return {
+        "in_proj": init_linear(ks[0], d, inner + conv_ch + H, dtype=dtype),
+        "conv_w": (jax.random.normal(ks[1], (cfg.conv1d_width, conv_ch),
+                                     jnp.float32)
+                   * cfg.conv1d_width ** -0.5).astype(dtype),
+        "conv_b": jnp.zeros((conv_ch,), dtype),
+        "A_log": jnp.log(jax.random.uniform(ks[2], (H,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": jnp.ones((H,), jnp.float32),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),     # softplus^-1(dt)
+        "norm": {"w": jnp.ones((inner,), dtype)},
+        "out_proj": init_linear(ks[4], inner, d, dtype=dtype),
+    }
+
+
+def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
+                Bm: jnp.ndarray, Cm: jnp.ndarray, chunk: int,
+                state0: Optional[jnp.ndarray] = None):
+    """The SSD recurrence h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T,
+    y_t = h_t C_t, in f32, chunk by chunk: mLSTM's algebra with q = C,
+    k = B, v = x dt and log-decay A dt, B and C shared by the heads.
+
+    x (B,S,H,P), dt (B,S,H), A (H,), Bm/Cm (B,S,N); ``state0``
+    (B,H,P,N) or zeros. Returns (y (B,S,H,P), final state (B,H,P,N))."""
+    Bb, S, H, P = x.shape
+    N = Bm.shape[-1]
+    L = min(chunk, S)
+    nc = -(-S // L)
+    pad = nc * L - S
+    f32 = jnp.float32
+    a = dt.astype(f32) * A.astype(f32)                  # log-decay (B,S,H)
+    v = x.astype(f32) * dt.astype(f32)[..., None]
+
+    def chunks(t):
+        # padded steps decay by exp(0) and add nothing: the state is exact
+        t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) \
+            if pad else t
+        return jnp.moveaxis(t.reshape((Bb, nc, L) + t.shape[2:]), 1, 0)
+
+    def chunk_fn(state, inp):
+        ai, vi, bi, ci = inp                 # (B,L,H) (B,L,H,P) (B,L,N) x2
+        cum = jnp.cumsum(jnp.moveaxis(ai, 1, 2), axis=-1)      # (B,H,L)
+        cb = jnp.einsum("btn,bsn->bts", ci, bi)
+        w = _causal_decay(cum) * cb[:, None]                    # (B,H,t,s)
+        intra = jnp.einsum("bhts,bshp->bthp", w, vi)
+        inter = jnp.einsum("btn,bhpn->bthp", ci, state) * \
+            jnp.moveaxis(jnp.exp(cum), 1, 2)[..., None]
+        tot = cum[..., -1]                                      # (B,H)
+        kdec = jnp.exp(tot[..., None] - cum)                    # (B,H,L)
+        state = state * jnp.exp(tot)[..., None, None] + jnp.einsum(
+            "bshp,bhs,bsn->bhpn", vi, kdec, bi)
+        return state, intra + inter
+
+    if state0 is None:
+        state0 = jnp.zeros((Bb, H, P, N), f32)
+    state, ys = jax.lax.scan(
+        chunk_fn, state0.astype(f32),
+        (chunks(a), chunks(v), chunks(Bm.astype(f32)),
+         chunks(Cm.astype(f32))))
+    y = jnp.moveaxis(ys, 0, 1).reshape(Bb, nc * L, H, P)[:, :S]
+    return y, state
+
+
+def _mamba2_in(cfg, ctx, p, x, col):
+    """in_proj, split into z, the conv's input x|B|C, and dt."""
+    inner, N = cfg.ssm_inner, cfg.ssm_state
+    zxbcdt = qlinear(ctx, x, p["in_proj"], subcol(col, "in_proj"))
+    return (zxbcdt[..., :inner], zxbcdt[..., inner:2 * inner + 2 * N],
+            zxbcdt[..., 2 * inner + 2 * N:])
+
+
+def _mamba2_streams(cfg, p, xbc, dt, buf=None):
+    """Conv + SiLU over x|B|C, then x (B,S,H,P), B, C and dt, in f32."""
+    inner, N = cfg.ssm_inner, cfg.ssm_state
+    xbc = jax.nn.silu(_causal_conv1d(xbc.astype(jnp.float32), p["conv_w"],
+                                     p["conv_b"], buf=buf))
+    B_, S = xbc.shape[:2]
+    xs = xbc[..., :inner].reshape(B_, S, cfg.ssm_heads, cfg.ssm_head_dim)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    return xs, xbc[..., inner:inner + N], xbc[..., inner + N:], dt
+
+
+def _mamba2_out(cfg, ctx, p, y, z, x_dtype, col):
+    """Gated RMSNorm of y * silu(z) over the inner width, then out_proj."""
+    B_, S = y.shape[:2]
+    g = y.reshape(B_, S, cfg.ssm_inner) * jax.nn.silu(z.astype(jnp.float32))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + cfg.norm_eps)
+    g = (g * p["norm"]["w"].astype(jnp.float32)).astype(x_dtype)
+    return qlinear(ctx, g, p["out_proj"], subcol(col, "out_proj"))
+
+
+def mamba2_fwd(cfg: ModelConfig, ctx: QuantCtx, p: Dict, x: jnp.ndarray,
+               col: Optional[Dict] = None, *, return_cache: bool = False):
+    """Training/prefill path. Only in_proj and out_proj carry quantizers
+    (A-bit input, W-bit weights); the conv, dt, the SSD, the D skip and the
+    gated norm run in f32. With ``return_cache`` also the dense serving
+    cache: the SSD state (C-bit) and the conv's last K-1 inputs."""
+    z, xbc, dt = _mamba2_in(cfg, ctx, p, x, col)
+    xs, Bm, Cm, dt = _mamba2_streams(cfg, p, xbc, dt)
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    with jax.named_scope("ssd"):
+        y, state = ssd_chunked(xs, dt, A, Bm, Cm, cfg.ssm_chunk)
+    y = y + p["D"].astype(jnp.float32)[:, None] * xs
+    out = _mamba2_out(cfg, ctx, p, y, z, x.dtype, col)
+    if not return_cache:
+        return out
+    K = cfg.conv1d_width
+    tail = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))[:, -(K - 1):]
+    return out, _mamba2_cache(ctx, state, tail)
+
+
+def _mamba2_cache(ctx, state, conv_buf):
+    sq, ss = cache_quantize(ctx, state.astype(jnp.bfloat16))
+    return {"state_q": sq, "s_state": ss,
+            "conv_buf": conv_buf.astype(jnp.bfloat16)}
+
+
+def init_mamba2_cache(cfg: ModelConfig, B: int, dtype=jnp.int8) -> Dict:
+    H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    return {"state_q": jnp.zeros((B, H, P, N), dtype),
+            "s_state": jnp.zeros((B, H, P, 1), jnp.float32),
+            "conv_buf": jnp.zeros((B, cfg.conv1d_width - 1,
+                                   cfg.ssm_inner + 2 * cfg.ssm_state),
+                                  jnp.bfloat16)}
+
+
+def mamba2_decode(cfg: ModelConfig, ctx: QuantCtx, p: Dict, x1: jnp.ndarray,
+                  cache: Dict) -> Tuple[jnp.ndarray, Dict]:
+    """One token: the conv over the cached inputs, one SSD step on the
+    dequantized state."""
+    z, xbc, dt = _mamba2_in(cfg, ctx, p, x1, None)
+    xs, Bm, Cm, dt = _mamba2_streams(cfg, p, xbc, dt, buf=cache["conv_buf"])
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    h = dequantize_int(cache["state_q"], cache["s_state"], jnp.float32)
+    x0, dt0, B0, C0 = xs[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0]
+    h = h * jnp.exp(dt0 * A)[..., None, None] + jnp.einsum(
+        "bhp,bn->bhpn", x0 * dt0[..., None], B0)
+    y = jnp.einsum("bhpn,bn->bhp", h, C0) + \
+        p["D"].astype(jnp.float32)[:, None] * x0
+    out = _mamba2_out(cfg, ctx, p, y[:, None], z, x1.dtype, None)
+    buf = jnp.concatenate([cache["conv_buf"][:, 1:],
+                           xbc.astype(jnp.bfloat16)], axis=1)
+    return out, _mamba2_cache(ctx, h, buf)

@@ -16,6 +16,8 @@ divide falls back to replication, never to a compile error):
   (mixtral-8e on a 16-way axis); router replicated
 * per-channel quantizer scales follow their weight's output sharding;
   per-tensor scales, norms and the recurrence diagonal replicate
+* the Mamba-2 mixer replicates whole (its in_proj output concatenates
+  z, x|B|C and dt, which no single column split keeps per head)
 * w4a8 export planes (``<linear>/w4a8/{wq,s_w,b,wf}``) shard like the
   linear they shadow: column-parallel owners split ``wq`` on d_out and
   ``s_w``/``b``/``wf`` on the output channel; row-parallel owners split
@@ -151,6 +153,11 @@ def param_spec(cfg: ModelConfig, mesh: Mesh, path: str,
         return lead(P(None, None))
     if key.startswith("s_"):            # per-tensor scalars
         return lead(P())
+
+    # ---- Mamba-2 mixer: replicated. Its in_proj output concatenates z,
+    # x|B|C and dt, so no one column split keeps a head's streams together
+    if "mamba" in parts:
+        return P()
 
     # ---- linears ----------------------------------------------------------------
     if key == "w" and parent in COL_PARALLEL:

@@ -86,6 +86,7 @@ class TestArchSmoke:
             "recurrentgemma-2b": (26, 2560, 10, 1, 7680, 256_000),
             "qwen2-vl-2b": (28, 1536, 12, 2, 8960, 151_936),
             "xlstm-125m": (12, 768, 4, 4, 0, 50_304),
+            "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100_352),
         }[arch]
         got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                cfg.d_ff, cfg.vocab_size)

@@ -177,19 +177,23 @@ def test_small_pool_blocks_refused_on_hardware(hardware):
                        jnp.zeros((1, 2), i32), jnp.ones((1,), i32))
 
 
-@pytest.mark.parametrize("mesh_shape", [None, (2, 2)],
-                         ids=["one_chip", "data2_model2"])
-def test_train_step_keeps_tied_table_row_major(topo, one_chip, mesh_shape):
+@pytest.mark.parametrize("mesh_shape,arch",
+                         [(None, "qwen2.5-3b"), ((2, 2), "qwen2.5-3b"),
+                          (None, "granite-4.0-h-micro")],
+                         ids=["one_chip", "data2_model2", "hybrid_one_chip"])
+def test_train_step_keeps_tied_table_row_major(topo, one_chip, mesh_shape,
+                                               arch):
     """Left to TPU layout assignment, the tied head's weight gradient comes
     out column-major and the step copies the table and both its AdamW
     moments to that layout and back. With the table's gradient in the
     arguments' layout the step copies none of its arguments: on one chip,
     and on a (data, model) mesh under the dry run's sharding rules, where
-    pinning every gradient would copy sharded weights and moments."""
+    pinning every gradient would copy sharded weights and moments; and in
+    the Mamba-2 + attention hybrid, whose embedding is scaled too."""
     V, d = 1024, 128
-    cfg = get_reduced_config("qwen2.5-3b").replace(
+    cfg = get_reduced_config(arch).replace(
         vocab_size=V, d_model=d, n_heads=4, n_kv_heads=2, head_dim=32,
-        d_ff=2 * d)
+        d_ff=2 * d, ssm_heads=8, ssm_head_dim=32)
     assert cfg.tie_embeddings
     params = param_struct(cfg)
     opt = opt_struct(params)
