@@ -17,9 +17,11 @@ TPU adaptation notes:
   used).
 * sLSTM has a non-linearizable hidden->gate feedback -> lax.scan over time
   (small matvecs; it exists in 2/12 layers of the assigned config).
-* Mamba-2's SSD is mLSTM's chunked algebra (q = C, k = B, v = x dt,
+* Mamba-2's SSD is mLSTM's chunk algebra (q = C, k = B, v = x dt,
   log-decay A dt) with B and C shared by all heads; its state is
-  (heads, head_dim, d_state).
+  (heads, head_dim, d_state). Its chunks run in parallel, not in a scan:
+  the states entering them come from one small causal-decay contraction
+  over the chunks' end states (Mamba-2's own chunk-parallel SSD).
 """
 from __future__ import annotations
 
@@ -440,12 +442,56 @@ def init_mamba2(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> Dict:
     }
 
 
+# most f32 bytes of one group's (B, chunks, H, L, L) decay block. With
+# granite's H 64 and L 256 a chunk of one row is 16 MiB: a 1 x 1024 QAT step
+# is one group of 4 chunks, a 1 x 32768 prompt 8 groups of 16
+SSD_GROUP_BYTES = 1 << 28
+
+
+def _ssd_group(state0, a, v, Bm, Cm):
+    """Every chunk of a group at once. a (B,G,L,H), v (B,G,L,H,P), Bm/Cm
+    (B,G,L,N), state0 (B,H,P,N). Returns (final state, y (B,G,L,H,P))."""
+    cum = jnp.cumsum(a, axis=2)                         # (B,G,L,H)
+    cum_h = jnp.swapaxes(cum, 2, 3)                     # (B,G,H,L)
+    # intra-chunk: (C_t . B_s) exp(cum_t - cum_s) for s <= t, onto v_s
+    cb = jnp.einsum("bctn,bcsn->bcts", Cm, Bm)
+    w = _causal_decay(cum_h) * cb[:, :, None]           # (B,G,H,t,s)
+    intra = jnp.einsum("bchts,bcshp->bcthp", w, v)
+    # each chunk's end state from a zero start
+    # static slices, not jnp's integer indexing: that lowers to a dynamic
+    # slice, whose gradient is a dynamic_update_slice into zeros
+    tot = jax.lax.index_in_dim(cum, -1, 2, keepdims=False)      # (B,G,H)
+    vdec = v * jnp.exp(tot[:, :, None] - cum)[..., None]
+    own = jnp.einsum("bcshp,bcsn->bchpn", vdec, Bm)     # (B,G,H,P,N)
+    # the state entering chunk z (z = G: the final state) is every earlier
+    # start state decayed by the log-decay between: a (G+1, G+1) causal
+    # decay over [0, tot_0, ..., tot_{G-1}], at full f32 precision: the
+    # default may round the states' terms to bf16 on a TPU
+    ctot = jnp.cumsum(jnp.pad(tot, ((0, 0), (1, 0), (0, 0))), axis=1)
+    starts = jnp.concatenate([state0[:, None], own], axis=1)
+    states = jnp.einsum("bhzc,bchpn->bzhpn",
+                        _causal_decay(jnp.swapaxes(ctot, 1, 2)), starts,
+                        precision=jax.lax.Precision.HIGHEST)
+    inter = jnp.einsum("bctn,bchpn->bcthp", Cm, states[:, :-1]) * \
+        jnp.exp(cum)[..., None]
+    return jax.lax.index_in_dim(states, -1, 1, keepdims=False), \
+        intra + inter
+
+
 def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
                 Bm: jnp.ndarray, Cm: jnp.ndarray, chunk: int,
                 state0: Optional[jnp.ndarray] = None):
     """The SSD recurrence h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T,
-    y_t = h_t C_t, in f32, chunk by chunk: mLSTM's algebra with q = C,
-    k = B, v = x dt and log-decay A dt, B and C shared by the heads.
+    y_t = h_t C_t, in f32, in Mamba-2's chunk-parallel form: with v = x dt
+    and log-decay A dt, every chunk's intra-chunk output and own end state
+    are one batched einsum over a chunk axis; the states entering the
+    chunks are a causal-decay matrix over the chunks' total log-decays
+    times their end states (``state0`` first); the inter-chunk output is
+    C times the state entering its chunk. No step runs in sequence.
+
+    Held at once is a (B, chunks, H, L, L) f32 block; where that exceeds
+    ``SSD_GROUP_BYTES`` (a long prompt), equal groups of chunks run in
+    turn, each starting from the state the last one left.
 
     x (B,S,H,P), dt (B,S,H), A (H,), Bm/Cm (B,S,N); ``state0``
     (B,H,P,N) or zeros. Returns (y (B,S,H,P), final state (B,H,P,N))."""
@@ -453,38 +499,30 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     N = Bm.shape[-1]
     L = min(chunk, S)
     nc = -(-S // L)
-    pad = nc * L - S
+    per_chunk = Bb * H * L * L * 4
+    ng = -(-nc // max(1, SSD_GROUP_BYTES // per_chunk))
+    G = -(-nc // ng)                                    # chunks per group
+    pad = ng * G * L - S
     f32 = jnp.float32
     a = dt.astype(f32) * A.astype(f32)                  # log-decay (B,S,H)
     v = x.astype(f32) * dt.astype(f32)[..., None]
 
-    def chunks(t):
+    def groups(t):
         # padded steps decay by exp(0) and add nothing: the state is exact
         t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) \
             if pad else t
-        return jnp.moveaxis(t.reshape((Bb, nc, L) + t.shape[2:]), 1, 0)
+        return jnp.moveaxis(t.reshape((Bb, ng, G, L) + t.shape[2:]), 1, 0)
 
-    def chunk_fn(state, inp):
-        ai, vi, bi, ci = inp                 # (B,L,H) (B,L,H,P) (B,L,N) x2
-        cum = jnp.cumsum(jnp.moveaxis(ai, 1, 2), axis=-1)      # (B,H,L)
-        cb = jnp.einsum("btn,bsn->bts", ci, bi)
-        w = _causal_decay(cum) * cb[:, None]                    # (B,H,t,s)
-        intra = jnp.einsum("bhts,bshp->bthp", w, vi)
-        inter = jnp.einsum("btn,bhpn->bthp", ci, state) * \
-            jnp.moveaxis(jnp.exp(cum), 1, 2)[..., None]
-        tot = cum[..., -1]                                      # (B,H)
-        kdec = jnp.exp(tot[..., None] - cum)                    # (B,H,L)
-        state = state * jnp.exp(tot)[..., None, None] + jnp.einsum(
-            "bshp,bhs,bsn->bhpn", vi, kdec, bi)
-        return state, intra + inter
-
-    if state0 is None:
-        state0 = jnp.zeros((Bb, H, P, N), f32)
-    state, ys = jax.lax.scan(
-        chunk_fn, state0.astype(f32),
-        (chunks(a), chunks(v), chunks(Bm.astype(f32)),
-         chunks(Cm.astype(f32))))
-    y = jnp.moveaxis(ys, 0, 1).reshape(Bb, nc * L, H, P)[:, :S]
+    state0 = jnp.zeros((Bb, H, P, N), f32) if state0 is None \
+        else state0.astype(f32)
+    xs = (groups(a), groups(v), groups(Bm.astype(f32)),
+          groups(Cm.astype(f32)))
+    if ng == 1:
+        state, y = _ssd_group(state0, *(jnp.squeeze(t, 0) for t in xs))
+        y = y[None]
+    else:
+        state, y = jax.lax.scan(lambda s, t: _ssd_group(s, *t), state0, xs)
+    y = jnp.moveaxis(y, 0, 1).reshape(Bb, ng * G * L, H, P)[:, :S]
     return y, state
 
 

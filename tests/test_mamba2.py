@@ -24,6 +24,7 @@ from repro.models import decode_step, forward, prefill  # noqa: E402
 from repro.models import blocks, model, recurrent  # noqa: E402
 
 CHUNK = 8
+SSD_B, SSD_H = 2, 3            # batch and heads of the SSD's test inputs
 
 
 def _bf16(t):
@@ -36,7 +37,7 @@ def _bf16(t):
 
 def _ssd_inputs(S, key):
     ks = jax.random.split(key, 6)
-    B, H, P, N = 2, 3, 4, 5
+    B, H, P, N = SSD_B, SSD_H, 4, 5
     x = jax.random.normal(ks[0], (B, S, H, P))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
     A = -jnp.exp(jax.random.uniform(ks[2], (H,), minval=0.0, maxval=2.0))
@@ -62,26 +63,20 @@ def _chunked(x, dt, A, Bm, Cm, h0):
     return recurrent.ssd_chunked(x, dt, A, Bm, Cm, CHUNK, state0=h0)
 
 
-# S: two whole chunks, a ragged last chunk, one chunk shorter than CHUNK
-@pytest.mark.parametrize("S", [16, 13, 5])
-def test_chunked_ssd_matches_the_sequential_recurrence(S):
-    """Both are f32: they differ by summation order alone (~1e-6 of the
-    values), while rounding the SSD's inputs to bf16 moves them by ~1e-3,
-    so 3e-5 of the largest value (and 1e-4 for gradients, whose sums are
-    longer) admits the one and not the other."""
+def _close(a, b, tol):
+    scale = float(jnp.max(jnp.abs(b)))
+    return float(jnp.max(jnp.abs(a - b))) <= tol * scale
+
+
+def _assert_matches_sequential(S):
+    """Values within 3e-5 and gradients (every input, ``state0`` among
+    them) within 1e-4 of the largest, against the per-token recurrence."""
     args = _ssd_inputs(S, jax.random.PRNGKey(S))
     ry, rh = jax.random.normal(jax.random.PRNGKey(99), (2,))
-
-    def close(a, b, tol):
-        scale = float(jnp.max(jnp.abs(b)))
-        return float(jnp.max(jnp.abs(a - b))) <= tol * scale
-
     want = _sequential(*args)
     got = _chunked(*args)
     for g, w in zip(got, want):
-        assert close(g, w, 3e-5)
-    rounded = _chunked(*[_bf16(a) for a in args])
-    assert not close(rounded[0], want[0], 3e-5)
+        assert _close(g, w, 3e-5)
 
     def loss(fn, *a):
         y, h = fn(*a)
@@ -91,7 +86,55 @@ def test_chunked_ssd_matches_the_sequential_recurrence(S):
     g_want = jax.grad(functools.partial(loss, _sequential), argn)(*args)
     g_got = jax.grad(functools.partial(loss, _chunked), argn)(*args)
     for g, w in zip(g_got, g_want):
-        assert close(g, w, 1e-4)
+        assert _close(g, w, 1e-4)
+    return args, want
+
+
+# S: two whole chunks, a ragged last chunk, one chunk shorter than CHUNK,
+# four whole chunks (as in the benchmark's cell), five with the last ragged
+@pytest.mark.parametrize("S", [16, 13, 5, 32, 37])
+def test_chunked_ssd_matches_the_sequential_recurrence(S):
+    """Both are f32: they differ by summation order alone (~1e-6 of the
+    values), while rounding the SSD's inputs to bf16 moves them by ~1e-3,
+    so 3e-5 of the largest value (and 1e-4 for gradients, whose sums are
+    longer) admits the one and not the other. From two chunks on, the
+    states entering the chunks come from the chunk-level decay matrix."""
+    args, want = _assert_matches_sequential(S)
+    rounded = _chunked(*[_bf16(a) for a in args])
+    assert not _close(rounded[0], want[0], 3e-5)
+
+
+# chunks a group may hold: 37 tokens are 5 chunks, so 5 groups of 1, or 3
+# groups of 2 of which the last is padded
+@pytest.mark.parametrize("per_group", [1, 2])
+def test_chunk_groups_carry_the_state_between_them(per_group, monkeypatch):
+    """Where one group may not hold every chunk (a long prompt), groups of
+    chunks run in turn, each from the state the last left: the same
+    recurrence, values and gradients."""
+    monkeypatch.setattr(recurrent, "SSD_GROUP_BYTES",
+                        per_group * SSD_B * SSD_H * CHUNK * CHUNK * 4)
+    _assert_matches_sequential(37)
+
+
+def test_ssd_backward_at_the_cells_shapes_runs_no_loop():
+    """The gradient through the SSD at the granite cell's shapes (1 x 1024
+    tokens, 64 heads of 64, state 128, chunk 256), lowered from shapes
+    alone: no ``while`` and no ``dynamic_update_slice``, so no chunk scan
+    stacks its blocks in the QAT step."""
+    B, S, H, P, N, L = 1, 1024, 64, 64, 128, 256
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct(s, f32) for s in
+              [(B, S, H, P), (B, S, H), (H,), (B, S, N), (B, S, N)]]
+
+    def loss(x, dt, A, Bm, Cm):
+        y, h = recurrent.ssd_chunked(x, dt, A, Bm, Cm, L)
+        return jnp.sum(y) + jnp.sum(h)
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
+        *shapes).as_text()
+    assert "stablehlo.dot_general" in text
+    assert "stablehlo.while" not in text
+    assert "stablehlo.dynamic_update_slice" not in text
 
 
 # --------------------------------------------------------------------------
